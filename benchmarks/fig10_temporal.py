@@ -30,6 +30,7 @@ import numpy as np
 
 from benchmarks.common import RESULTS_DIR, emit
 from repro.codecs import get_codec
+from repro.compile_cache import enable_compile_cache
 from repro.temporal import VersionedStore, drifting_versions
 
 MIN_TT_RATIO = 3.0  # acceptance floor on the deterministic TT cell
@@ -140,4 +141,5 @@ def run(smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(smoke="--smoke" in sys.argv)

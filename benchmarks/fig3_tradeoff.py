@@ -13,6 +13,7 @@ import time
 
 from benchmarks.common import FULL, emit, save_rows
 from repro.codecs import available, get_codec
+from repro.compile_cache import enable_compile_cache
 from repro.data import synthetic_tensors as st
 
 DATASETS = ["uber", "air_quality", "stock", "nyc"] if not FULL else list(st.DATASETS)
@@ -58,4 +59,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -10,6 +10,7 @@ import numpy as np
 from benchmarks.common import FULL, emit, save_rows
 from repro.codecs import get_codec
 from repro.codecs.indexing import flat_to_multi
+from repro.compile_cache import enable_compile_cache
 from repro.core.folding import make_folding_spec
 from repro.data import synthetic_tensors as st
 
@@ -59,4 +60,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

@@ -31,6 +31,7 @@ from benchmarks.common import (
     scaling_budget,
 )
 from repro.codecs import available, get_codec
+from repro.compile_cache import enable_compile_cache
 
 SIZES = [(16, 16, 16), (24, 24, 24), (32, 32, 32), (48, 48, 48)]
 if FULL:
@@ -155,6 +156,7 @@ def run_stream(smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     if "--stream" in sys.argv:
         run_stream(smoke="--smoke" in sys.argv)
     else:

@@ -20,6 +20,7 @@ from benchmarks.common import (
     timeit,
 )
 from repro.codecs import available, get_codec
+from repro.compile_cache import enable_compile_cache
 
 EXPS = [6, 8, 10, 12] + ([14] if FULL else [])
 N_QUERIES = 1 << 14
@@ -64,4 +65,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

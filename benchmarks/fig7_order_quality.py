@@ -11,6 +11,7 @@ import numpy as np
 
 from benchmarks.common import emit, save_rows
 from repro.codecs import get_codec
+from repro.compile_cache import enable_compile_cache
 from repro.core import reorder
 
 
@@ -59,4 +60,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

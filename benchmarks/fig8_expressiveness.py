@@ -10,6 +10,7 @@ import numpy as np
 
 from benchmarks.common import emit, save_rows
 from repro.codecs import get_codec
+from repro.compile_cache import enable_compile_cache
 from repro.core import nttd
 from repro.core.folding import make_folding_spec
 
@@ -56,4 +57,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

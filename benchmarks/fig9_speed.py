@@ -6,6 +6,7 @@ import time
 
 from benchmarks.common import FULL, emit, save_rows
 from repro.codecs import available, get_codec
+from repro.compile_cache import enable_compile_cache
 from repro.data import synthetic_tensors as st
 
 NTTD_OPTS = dict(rank=6, hidden=12, epochs=40 if not FULL else 150,
@@ -43,4 +44,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

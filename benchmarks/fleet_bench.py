@@ -39,6 +39,7 @@ import time
 import numpy as np
 
 from benchmarks.common import RESULTS_DIR, emit
+from repro.compile_cache import enable_compile_cache
 from repro.fleet import FleetFrontend, SocketTransport, collect, rebalance
 from repro.serve.codec_service import CodecService
 
@@ -287,6 +288,7 @@ def run(smoke: bool = False, procs: int | None = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     procs = None
     if "--procs" in sys.argv:
         procs = int(sys.argv[sys.argv.index("--procs") + 1])

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import RESULTS_DIR, emit
+from repro.compile_cache import enable_compile_cache
 from repro.core import nttd
 from repro.core.folding import make_folding_spec
 from repro.kernels import ops
@@ -147,4 +148,5 @@ def run(smoke: bool = False) -> None:
 if __name__ == "__main__":
     import sys
 
+    enable_compile_cache()
     run(smoke="--smoke" in sys.argv)

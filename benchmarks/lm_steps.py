@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import emit
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.optim import optimizers
 from repro.train import step as step_lib
@@ -44,4 +45,5 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run()

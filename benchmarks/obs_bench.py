@@ -42,6 +42,7 @@ import numpy as np
 from benchmarks.common import RESULTS_DIR, emit
 from benchmarks.fleet_bench import _batches, _ensure_nttd_payload
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.fleet import FleetFrontend, SocketTransport, collect
 
 TRACE_OUT = os.path.join(RESULTS_DIR, "obs_trace.json")
@@ -267,6 +268,7 @@ def run(smoke: bool = False, procs: int | None = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     procs = None
     if "--procs" in sys.argv:
         procs = int(sys.argv[sys.argv.index("--procs") + 1])

@@ -9,6 +9,8 @@ from __future__ import annotations
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 MODULES = [
     "benchmarks.table2_stats",
     "benchmarks.fig3_tradeoff",
@@ -27,6 +29,7 @@ MODULES = [
 def main() -> None:
     import importlib
 
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
     failed = []

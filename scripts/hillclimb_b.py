@@ -58,7 +58,7 @@ def measure(tag: str, cfg_override=None, rules_override=None, depths=(2, 4)):
 
     def costs(depth):
         comp = lower(depth).compile()
-        cost = dryrun.cost_dict(comp)
+        cost = comp.cost_analysis()
         coll = dryrun.collective_bytes_per_device(comp.as_text(), by_dtype=True)
         return (float(cost.get("flops", 0.0)),
                 float(cost.get("bytes accessed", 0.0)), coll)

@@ -118,10 +118,12 @@ def load_bytes(
 
     ``kernel_impl`` picks the decode backend of the rebuilt payload (the
     wire format carries no impl — it is an execution choice, not data).
-    Default is "ref" for historical bit-stability; ``REPRO_DECODE_IMPL``
-    overrides it process-wide, which is how serving benches opt whole
-    worker fleets into the fused decode path without touching payloads.
+    Default is "fused" on a TPU, so served decodes run the Pallas tile,
+    and "ref" elsewhere, which keeps CPU decodes of stored payloads
+    bit-stable.  ``REPRO_DECODE_IMPL`` overrides it process-wide.
     """
+    import jax
+
     from repro.core.folding import make_folding_spec
 
     buf = io.BytesIO(data)
@@ -141,12 +143,12 @@ def load_bytes(
     cfg = nttd.NTTDConfig(
         rank=rank,
         hidden=hidden,
-        kernel_impl=kernel_impl or os.environ.get("REPRO_DECODE_IMPL", "ref"),
+        kernel_impl=kernel_impl
+        or os.environ.get("REPRO_DECODE_IMPL")
+        or ("fused" if jax.default_backend() == "tpu" else "ref"),
     )
     dtype = _DTYPES[code]
     # rebuild an abstract params tree to know the shapes, then fill
-    import jax
-
     template = jax.eval_shape(
         lambda key: nttd.init_params(key, spec, cfg), jax.random.PRNGKey(0)
     )

@@ -10,6 +10,7 @@ EXPERIMENTS.md can report how close the replicas are.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Callable
 
 import numpy as np
@@ -157,7 +158,8 @@ DATASETS: dict[str, DatasetSpec] = {
 
 def load(name: str, mini: bool = True, seed: int = 0) -> np.ndarray:
     spec = DATASETS[name]
-    rng = np.random.default_rng(seed + hash(name) % 2**31)
+    # crc32, not hash(): str hashes are salted per process
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()) % 2**31)
     shape = spec.mini_shape if mini else spec.shape
     return spec.generator(shape, rng).astype(np.float32)
 

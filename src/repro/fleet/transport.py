@@ -597,7 +597,19 @@ class SocketTransport:
         ``debug_flush_sleep_ms`` (latency), ``debug_corrupt_chunk``
         (``NAME:CHUNK`` entries) and ``debug_fitness_noise``
         (``NAME:LO:HI:SIGMA[:SEED]`` entries) are the worker's fault
-        injectors for SLO/repair drills; leave unset outside tests."""
+        injectors for SLO/repair drills; leave unset outside tests.
+
+        Refused where JAX's backend is a TPU: a chip belongs to one
+        process, so children that reach for it would fail or hang."""
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"cannot spawn worker {instance_id!r}: JAX's backend here is a "
+                "TPU, and worker processes cannot share a chip with this "
+                "process or with each other until each worker is given a "
+                "device of its own; serve in process (LocalTransport) instead"
+            )
         sock_dir = None
         if address is None:
             sock_dir = tempfile.mkdtemp(prefix="repro-fleet-")

@@ -41,6 +41,7 @@ import sys
 import time
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.fleet.transport import (
     FLUSH_HAS_CTX,
     FLUSH_WANT_SPANS,
@@ -333,6 +334,7 @@ def main(argv: list[str] | None = None) -> int:
         "entry range (repeatable; applied when the payload loads)",
     )
     args = parser.parse_args(argv)
+    enable_compile_cache()
     fault_specs = parse_fault_flags(
         args.debug_corrupt_chunk, args.debug_fitness_noise
     )

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_TILE_B = 256
+# f32 contraction on the MXU: Mosaic's default rounds f32 operands to bf16
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kernel(
@@ -70,11 +72,11 @@ def _kernel(
         onehot = (idx_ref[:, t][:, None] == lanes).astype(jnp.float32)
         xt = jnp.dot(
             onehot, emb_ref[t, :, :].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
+            precision=_F32, preferred_element_type=jnp.float32,
         )  # [TB, H]
         gates = (
-            jnp.dot(xt, wi, preferred_element_type=jnp.float32)
-            + jnp.dot(h, wh, preferred_element_type=jnp.float32)
+            jnp.dot(xt, wi, precision=_F32, preferred_element_type=jnp.float32)
+            + jnp.dot(h, wh, precision=_F32, preferred_element_type=jnp.float32)
             + b
         )
         i = jax.nn.sigmoid(gates[:, :hid])
@@ -86,25 +88,36 @@ def _kernel(
         if t == 0:
             v = (
                 jnp.dot(h, wf_ref[...].astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
+                        precision=_F32, preferred_element_type=jnp.float32)
                 + bf_ref[...].astype(jnp.float32)
             )
         elif t == t_steps - 1:
             last = (
                 jnp.dot(h, wl_ref[...].astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
+                        precision=_F32, preferred_element_type=jnp.float32)
                 + bl_ref[...].astype(jnp.float32)
             )
-            out = jnp.sum(v * last, axis=-1)
+            out = jnp.sum(v * last, axis=-1, keepdims=True)  # [TB, 1]
         else:
             mid = (
                 jnp.dot(h, wm_ref[...].astype(jnp.float32),
-                        preferred_element_type=jnp.float32)
+                        precision=_F32, preferred_element_type=jnp.float32)
                 + bm_ref[...].astype(jnp.float32)
-            ).reshape(tb, rank, rank)
-            # lane-parallel batched matvec on the VPU (R is tiny)
-            v = jnp.sum(v[:, :, None] * mid, axis=1)
+            )  # [TB, R*R], row-major R x R per entry
+            v = chain_step(v, mid, rank)
     out_ref[...] = out.astype(out_ref.dtype)
+
+
+def chain_step(v: jax.Array, mid: jax.Array, rank: int) -> jax.Array:
+    """``v[b, s] = sum_r v[b, r] * mid[b, r*R + s]``: one link of the TT
+    chain as R lane slices multiply-accumulated on the VPU (R is tiny).
+    Mosaic refuses the [TB, R*R] -> [TB, R, R] reshape, so the R x R core
+    stays flat; ``ref.nttd_decode_tile`` sums in this same order, which
+    keeps interpret mode bit-identical to the oracle."""
+    acc = v[:, 0:1] * mid[:, 0:rank]
+    for r in range(1, rank):
+        acc = acc + v[:, r : r + 1] * mid[:, r * rank : (r + 1) * rank]
+    return acc
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
@@ -160,7 +173,8 @@ def decode_tile(
             pl.BlockSpec((hid, rank), lambda i: (0, 0)),
             pl.BlockSpec((rank,), lambda i: (0,)),
         ],
-        out_specs=pl.BlockSpec((tile_b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((bsz,), emb.dtype),
+        # a [B, 1] column: a 1-D output block's layout disagrees with XLA's
+        out_specs=pl.BlockSpec((tile_b, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, 1), emb.dtype),
         interpret=interpret,
-    )(idx, emb, wi, wh, b, w_first, b_first, w_mid, b_mid, w_last, b_last)
+    )(idx, emb, wi, wh, b, w_first, b_first, w_mid, b_mid, w_last, b_last)[:, 0]

@@ -162,10 +162,12 @@ def nttd_decode_tile(
             last = h @ w_last.astype(jnp.float32) + b_last.astype(jnp.float32)
             out = jnp.sum(v * last, axis=-1)
         else:
-            mid = (
-                h @ w_mid.astype(jnp.float32) + b_mid.astype(jnp.float32)
-            ).reshape(bsz, rank, rank)
-            v = jnp.sum(v[:, :, None] * mid, axis=1)
+            mid = h @ w_mid.astype(jnp.float32) + b_mid.astype(jnp.float32)
+            # v[b, s] = sum_r v[b, r] * mid[b, r*R + s], summed over r in order
+            acc = v[:, 0:1] * mid[:, 0:rank]
+            for r in range(1, rank):
+                acc = acc + v[:, r : r + 1] * mid[:, r * rank : (r + 1) * rank]
+            v = acc
     return out.astype(emb.dtype)
 
 

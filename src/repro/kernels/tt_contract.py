@@ -6,7 +6,7 @@ column.  R is small (4..32), so a 128x128 MXU pass would be >94% idle —
 this is restructured as a *lane-parallel batched matvec*: the batch is
 tiled into VMEM blocks of TILE_B rows (sublane axis), and the per-step
 contraction v[b,s] = sum_r v[b,r] * M[b,k,r,s] is an unrolled VPU
-multiply-accumulate over the tiny R axis.
+multiply-accumulate over the tiny R axis (``decode_tile.chain_step``).
 
 HBM traffic: each core tensor is read exactly once; the running vector
 stays in registers/VMEM across all K steps (the fusion the XLA path
@@ -20,22 +20,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.decode_tile import chain_step
+
 DEFAULT_TILE_B = 256
 
 
-def _kernel(first_ref, mid_ref, last_ref, out_ref, *, k_steps: int):
+def _kernel(first_ref, mid_ref, last_ref, out_ref, *, k_steps: int, rank: int):
     v = first_ref[...].astype(jnp.float32)  # [TB, R]
-
-    def body(k, v):
-        m = mid_ref[:, k].astype(jnp.float32)  # [TB, R, R]
-        # lane-parallel batched matvec on the VPU (R is tiny)
-        return jnp.sum(v[:, :, None] * m, axis=1)
-
-    if k_steps > 0:
-        v = jax.lax.fori_loop(0, k_steps, body, v)
-    out_ref[...] = jnp.sum(v * last_ref[...].astype(jnp.float32), axis=1).astype(
-        out_ref.dtype
-    )
+    rr = rank * rank
+    for k in range(k_steps):  # K = d' - 2 is tiny: unrolled at trace time
+        v = chain_step(v, mid_ref[:, k * rr : (k + 1) * rr].astype(jnp.float32), rank)
+    out_ref[...] = jnp.sum(
+        v * last_ref[...].astype(jnp.float32), axis=1, keepdims=True
+    ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
@@ -57,15 +54,19 @@ def tt_contract(
     if bsz % tile_b:
         raise ValueError(f"batch {bsz} not a multiple of tile_b {tile_b}")
     grid = (bsz // tile_b,)
+    # cores flat on lanes: an [R, R] minor block would pad to (8, 128)
+    # tiles, about 20x the VMEM at R = 10
+    mid = mid.reshape(bsz, k_steps * r * r)
     return pl.pallas_call(
-        functools.partial(_kernel, k_steps=k_steps),
+        functools.partial(_kernel, k_steps=k_steps, rank=r),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_b, r), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, k_steps, r, r), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((tile_b, k_steps * r * r), lambda i: (i, 0)),
             pl.BlockSpec((tile_b, r), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((bsz,), first.dtype),
+        # a [B, 1] column: a 1-D output block's layout disagrees with XLA's
+        out_specs=pl.BlockSpec((tile_b, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bsz, 1), first.dtype),
         interpret=interpret,
-    )(first, mid, last)
+    )(first, mid, last)[:, 0]

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import SHAPES
 from repro.dist import sharding
 from repro.launch import mesh as mesh_lib
@@ -96,14 +97,6 @@ def collective_bytes_per_device(hlo_text: str, by_dtype: bool = False) -> dict[s
                 out[key] = out.get(key, 0.0) + n * _DTYPE_BYTES[dtype] * _COLL_FACTOR[kind]
     out["total"] = sum(v for k, v in out.items() if ":" not in k)
     return out
-
-
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on new jax, [dict] on old."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 
 def model_flops(cfg, shape) -> float:
@@ -244,7 +237,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, rules_name: str = "base
             arch, shape_name, mesh, rules_name, remat, seq_shard, depth_blocks=depth
         )
         comp = low.compile()
-        cost = cost_dict(comp)
+        cost = comp.cost_analysis()
         coll = collective_bytes_per_device(comp.as_text())
         return (
             float(cost.get("flops", 0.0)),
@@ -357,6 +350,7 @@ def main():
     ap.add_argument("--remat", default=None, choices=[None, "none", "dots", "full"])
     ap.add_argument("--seq-shard", default=None, type=int, choices=[0, 1])
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     if args.all:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import codec as codec_lib
 from repro.core import nttd
 from repro.core.folding import make_folding_spec
@@ -65,7 +66,7 @@ def run(mesh_name: str, impl: str, batch: int, steps: int, rank: int,
     ).lower(ab_params, ab_opt, pos, vals)
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
-    cost = dryrun.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = dryrun.collective_bytes_per_device(compiled.as_text())
 
     # cost_analysis under-counts the steps-loop (while); per-step numbers
@@ -141,6 +142,7 @@ def main():
     ap.add_argument("--rank", type=int, default=8)
     ap.add_argument("--hidden", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
     res = run(args.mesh, args.impl, args.batch, args.steps, args.rank, args.hidden)
     path = dryrun.cell_path("tensorcodec-codec", f"b{args.batch}-{args.impl}",
                             args.mesh, "dp")

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.serve.engine import Request, ServeEngine
 
@@ -27,6 +28,7 @@ def main(argv=None):
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     params = model.init_params(jax.random.PRNGKey(0), cfg)

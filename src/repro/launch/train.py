@@ -21,8 +21,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.data.pipeline import PipelineConfig, SyntheticSource
 from repro.dist import sharding
+from repro.launch.mesh import make_mesh
 from repro.models import model
 from repro.optim import optimizers, schedules
 from repro.train import checkpoint as ckpt_lib
@@ -65,14 +67,15 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None,
                     help="DxM, e.g. 2x2 (default: all devices data-parallel)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     n_dev = len(jax.devices())
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     else:
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = make_mesh((n_dev, 1), ("data", "model"))
 
     sched = {
         "wsd": schedules.wsd(args.lr, args.steps, warmup=min(20, args.steps // 10)),

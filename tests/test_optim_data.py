@@ -136,3 +136,24 @@ def test_topk_error_feedback_preserves_mass():
     g2, state = comp.transform(grads, state)
     total = np.asarray(g1["w"] + g2["w"])
     assert total.sum() > np.asarray(grads["w"]).sum()  # catching up on skipped mass
+
+
+# ----------------------------------------------------------- synthetic data
+def test_synthetic_tensor_same_in_every_process():
+    """Seeded tensors must not depend on the process's string-hash salt."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import hashlib; from repro.data import synthetic_tensors as st; "
+        "print(hashlib.sha256(st.load('pems_sf', seed=5).tobytes()).hexdigest())"
+    )
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    digests = set()
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, PYTHONPATH=src)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120, check=True)
+        digests.add(out.stdout.strip())
+    assert len(digests) == 1

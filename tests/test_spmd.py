@@ -43,7 +43,8 @@ def test_pjit_train_step_matches_single_device():
     p1, o1, m1 = jax.jit(step1)(params, opt.init(params), batch)
 
     # 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = sharding.BASE_RULES
     ps = step_lib.param_shardings(mesh, cfg, rules)
     with sharding.sharding_ctx(mesh, rules):

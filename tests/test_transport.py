@@ -211,6 +211,22 @@ def test_socket_and_local_transport_bit_identical(payload_path):
         remote.submit("t", batches[0])
 
 
+def test_spawn_refused_on_tpu_host(monkeypatch):
+    """On a TPU host a spawned worker would reach for the chip this
+    process holds: spawn refuses before starting any child."""
+    import subprocess
+
+    import jax
+
+    def no_child(*a, **kw):
+        raise AssertionError("spawn started a child process")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(subprocess, "Popen", no_child)
+    with pytest.raises(RuntimeError, match="device of its own"):
+        SocketTransport.spawn("w0")
+
+
 def test_spawned_socket_dir_removed_on_close(payload_path):
     remote = _spawn("w0")
     sock_dir = remote._owned_dir
