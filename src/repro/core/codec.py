@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.codecs.indexing import flat_to_multi
 from repro.core import nttd, reorder
 from repro.core.folding import FoldingSpec, make_folding_spec
@@ -77,12 +78,20 @@ class CompressedTensor:
 
     # -- reconstruction ------------------------------------------------------
     def decode(self, indices: np.ndarray) -> np.ndarray:
-        """Approximate entries at ORIGINAL indices [B, d] -> [B]."""
-        pos = self._orig_to_pos(indices)
-        vals = nttd.apply_at_positions(
-            self.params, jnp.asarray(pos, jnp.int32), self.spec, self.cfg
-        )
-        return np.asarray(vals) * self.norm_std + self.norm_mean
+        """Approximate entries at ORIGINAL indices [B, d] -> [B].
+
+        Spans: ``payload.orig_to_pos`` (the host gather), ``nttd.fold``
+        (upload and the eager fold), then ``nttd.apply``'s own, and
+        ``payload.device_wait`` (the host blocked on the answer)."""
+        with obs.span("payload.decode", entries=len(indices)):
+            with obs.span("payload.orig_to_pos"):
+                pos = self._orig_to_pos(indices)
+            with obs.span("nttd.fold"):
+                folded = self.spec.fold_indices(jnp.asarray(pos, jnp.int32))
+            vals = nttd.apply(self.params, folded, self.spec, self.cfg)
+            with obs.span("payload.device_wait"):
+                vals = np.asarray(vals)
+            return vals * self.norm_std + self.norm_mean
 
     def to_dense(self, batch: int = 65536) -> np.ndarray:
         """Full reconstruction in ORIGINAL index order."""
@@ -169,12 +178,14 @@ def _make_train_epoch(spec: FoldingSpec, cfg: nttd.NTTDConfig, opt):
     def epoch(params, opt_state, positions, values):
         # positions: [S, B, d] int32; values: [S, B]
         def body(carry, xs):
-            params, opt_state = carry
-            pos, val = xs
-            loss, grads = jax.value_and_grad(loss_fn)(params, pos, val)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optimizers.apply_updates(params, updates)
-            return (params, opt_state), loss
+            # a stable name for the step's operations in a device trace
+            with jax.named_scope("train_epoch"):
+                params, opt_state = carry
+                pos, val = xs
+                loss, grads = jax.value_and_grad(loss_fn)(params, pos, val)
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optimizers.apply_updates(params, updates)
+                return (params, opt_state), loss
 
         (params, opt_state), losses = jax.lax.scan(
             body, (params, opt_state), (positions, values)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.folding import FoldingSpec
 from repro.kernels import ops
 
@@ -120,10 +121,10 @@ def apply(
     if cfg.kernel_impl == "fused" and d_prime >= 2:
         # single-program decode: whole chain in one kernel / one XLA program
         # (Pallas on TPU, jitted oracle on CPU — see kernels.ops)
+        with obs.span("nttd.operands"):
+            operands = fused_decode_inputs(params, spec, cfg)
         return ops.nttd_decode_tile(
-            folded_idx.astype(jnp.int32),
-            *fused_decode_inputs(params, spec, cfg),
-            impl="fused",
+            folded_idx.astype(jnp.int32), *operands, impl="fused"
         )
     # --- embedding lookup (shared tables by mode length) -------------------
     embeds = [

@@ -177,4 +177,5 @@ def decode_tile(
         out_specs=pl.BlockSpec((tile_b, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, 1), emb.dtype),
         interpret=interpret,
+        name="decode_tile",  # a stable kernel name in device traces
     )(idx, emb, wi, wh, b, w_first, b_first, w_mid, b_mid, w_last, b_last)[:, 0]

@@ -170,19 +170,26 @@ def nttd_decode_tile(
     See ``decode_tile.decode_tile`` for operand layout.  Batch padding to
     the Pallas tile is handled here; B == 0 short-circuits (a zero-size
     grid is invalid in Pallas).
+
+    The ``kernel_decode`` span times the padding and the enqueue of an
+    asynchronous call, not the kernel: the kernel's device time is
+    ``jit_decode_tile`` in a profiler trace.  ``b`` and ``padded`` count
+    the rows asked for and the rows the kernel runs.
     """
-    if idx.shape[0] == 0:
+    bsz = idx.shape[0]
+    if bsz == 0:
         return jnp.zeros((0,), emb.dtype)
     if impl in ("auto", "fused"):
         impl = "pallas" if jax.default_backend() == "tpu" else "fused"
     heads = (w_first, b_first, w_mid, b_mid, w_last, b_last)
-    with obs.span("kernel_decode", impl=impl, b=int(idx.shape[0])):
+    tile = tile_b or min(_dt.DEFAULT_TILE_B, max(8, bsz))
+    padded = bsz if impl in ("ref", "fused") else bsz + (-bsz) % tile
+    with obs.span("kernel_decode", impl=impl, b=bsz, padded=padded):
         if impl == "ref":
             return _ref.nttd_decode_tile(idx, emb, wi, wh, b, *heads)
         if impl == "fused":
             return _fused_oracle(idx, emb, wi, wh, b, *heads)
-        tile = tile_b or min(_dt.DEFAULT_TILE_B, max(8, idx.shape[0]))
-        idx_p, bsz = _pad_batch(idx, tile)
+        idx_p, _ = _pad_batch(idx, tile)
         out = _dt.decode_tile(
             idx_p, emb, wi, wh, b, *heads,
             tile_b=tile, interpret=impl == "pallas_interpret",

@@ -6,9 +6,16 @@ request spends its time.  This package threads spans through the whole
 pipeline — ``FleetFrontend.decode_at`` → ``Transport`` wire →
 ``repro.fleet.worker`` → ``CodecService`` stages (``chunk_read``,
 ``materialize``, ``tile_decode``, ``prefetch_wait``, ``coalesce_flush``)
-→ the fused ``kernel_decode`` — stitches worker spans back into one
-cross-process trace, and exports Chrome trace-event JSON that Perfetto
-loads directly.
+→ ``CompressedTensor.decode`` (``payload.decode``, ``payload.orig_to_pos``,
+``nttd.fold``, ``nttd.operands``, ``payload.device_wait``) → the fused
+``kernel_decode`` — stitches worker spans back into one cross-process
+trace, and exports Chrome trace-event JSON that Perfetto loads directly.
+``kernel_decode`` times the padding and the enqueue of an asynchronous
+call; the kernel's device time is ``jit_decode_tile`` in a profiler trace.
+The streaming fit records ``fit.update`` with ``fit.sample``,
+``fit.dispatch`` and ``fit.reservoir`` inside it.  Every live span is
+also a ``jax.profiler.TraceAnnotation``, so a profiler trace holds the
+spans on its device events' clock.
 
     from repro import obs
 

@@ -16,7 +16,11 @@ Cost model — the whole point of this module:
 - **enabled** (``REPRO_TRACE=1`` or :func:`enable_tracing`): one small
   object + two ``perf_counter`` calls per span, appended to a
   ``maxlen``-bounded deque, so memory is capped no matter how long the
-  process serves.
+  process serves.  Each live span also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name (about a microsecond
+  outside a profiler session), so under ``jax.profiler.trace`` every span
+  sits in the trace's host plane on the device events' clock, and an idle
+  stretch of the device can be charged to the innermost span open over it.
 
 Cross-process stitching: a worker adopts the frontend's (trace id,
 span id) via :func:`remote_context`, records its spans against ITS
@@ -31,6 +35,7 @@ import collections
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import itertools
 import os
 import time
@@ -79,10 +84,20 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
-class _LiveSpan:
-    """An open span: created by ``TraceRecorder.span`` when enabled."""
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use so that
+    reading or exporting spans never imports JAX."""
+    from jax.profiler import TraceAnnotation
 
-    __slots__ = ("_rec", "_span", "_token")
+    return TraceAnnotation
+
+
+class _LiveSpan:
+    """An open span: created by ``TraceRecorder.span`` when enabled.  It
+    holds the profiler annotation of the same name beside the ``Span``."""
+
+    __slots__ = ("_rec", "_span", "_token", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, attrs: dict):
         self._rec = rec
@@ -96,6 +111,8 @@ class _LiveSpan:
         rec.span_allocs += 1
 
     def __enter__(self) -> Span:
+        self._ann = _annotation()(self._span.name)
+        self._ann.__enter__()
         self._token = _CTX.set((self._span.trace_id, self._span.span_id))
         self._span.t_start = time.perf_counter()
         return self._span
@@ -103,6 +120,7 @@ class _LiveSpan:
     def __exit__(self, exc_type, exc, tb) -> None:
         self._span.t_end = time.perf_counter()
         _CTX.reset(self._token)
+        self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self._span.attrs = dict(self._span.attrs, error=exc_type.__name__)
         self._rec._append(self._span)
@@ -177,7 +195,8 @@ class TraceRecorder:
         """Stitch spans recorded on ANOTHER process's clock into this
         buffer: ``clock_offset`` (this process's ``perf_counter`` minus the
         remote one, sampled at reply time) re-bases their timestamps onto
-        the local timeline; ``instance`` labels who recorded them."""
+        the local timeline; ``instance`` labels who recorded them.  They
+        carry no profiler annotation: another process recorded them."""
         for s in spans:
             if clock_offset:
                 s = dataclasses.replace(

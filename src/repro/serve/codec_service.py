@@ -1228,7 +1228,9 @@ class CodecService:
         only work that actually decoded.  ``version`` selects a v4
         payload's version (default: latest); single-tensor payloads
         reject it."""
-        with obs.span("decode_at", payload=name, entries=int(np.size(indices))):
+        dims = np.shape(indices)  # [B, d] once validated
+        with obs.span("decode_at", payload=name,
+                      entries=int(dims[0]) if len(dims) == 2 else 0):
             sp = self._streams.get(name)
             if sp is not None and sp.versions is not None:
                 v = self._resolve_version(name, sp, version)

@@ -95,6 +95,11 @@ class NTTDStreamFitter(StreamFitter):
         self._inv: list[np.ndarray] | None = None
 
     def update(self, indices: np.ndarray, values: np.ndarray) -> None:
+        """Train on one slab.  Spans: ``fit.update`` (the whole update),
+        ``fit.sample`` (index remap, normalisation, the minibatch draw),
+        ``fit.dispatch`` (the uploads and the train-epoch call, which
+        returns before the device is done) and ``fit.reservoir``."""
+        t0 = time.perf_counter()
         idx = np.asarray(indices, dtype=np.int64)
         vals = np.asarray(values, dtype=np.float32).ravel()
         if idx.ndim != 2 or idx.shape[1] != len(self.shape) or idx.shape[0] != len(vals):
@@ -102,6 +107,40 @@ class NTTDStreamFitter(StreamFitter):
                 f"slab must be indices [B, {len(self.shape)}] + values [B], "
                 f"got {idx.shape} / {vals.shape}"
             )
+        with obs.span("fit.update", entries=len(vals)):
+            with obs.span("fit.sample"):
+                idx, vn, rng, pos, val = self._sample(idx, vals)
+            with obs.span("fit.dispatch"):
+                self.params, self._opt_state, loss = self._epoch(
+                    self.params,
+                    self._opt_state,
+                    jnp.asarray(pos, jnp.int32),
+                    jnp.asarray(val, jnp.float32),
+                )
+            with obs.span("fit.reservoir"):
+                self._reservoir_insert(idx, vn, rng)
+            self.entries_seen += len(vn)
+            self.slabs_seen += 1
+            if obs.fit_telemetry_enabled():
+                # float(loss) forces a device sync — only pay it when
+                # logging; the rate is timed up to it, so it counts the
+                # train step's device time and not only its enqueue
+                loss = float(loss)
+                elapsed = time.perf_counter() - t0
+                obs.fit_event(
+                    "fit_slab",
+                    codec="nttd",
+                    step=self.slabs_seen - 1,
+                    loss=loss,
+                    entries=len(vn),
+                    entries_per_sec=len(vn) / elapsed if elapsed > 0 else None,
+                    reservoir_fill=self._rfill,
+                    reservoir_capacity=int(self._rval.shape[0]),
+                )
+
+    def _sample(self, idx: np.ndarray, vals: np.ndarray):
+        """Slab in position space, normalised values, the slab's RNG and
+        the fixed-shape [steps, bsz] batches mixing fresh and replay."""
         if self._inv is not None:
             # train in POSITION space (X_pi(pos) = X(pi(pos)), the same
             # convention core/codec.py uses); decode maps back via inv_pi
@@ -119,8 +158,6 @@ class NTTDStreamFitter(StreamFitter):
         rng = np.random.default_rng(
             (self.seed * 1_000_003 + self.slabs_seen) * 131 + 29
         )
-
-        # ---- train: fixed-shape [steps, bsz] batches mixing fresh + replay
         steps, bsz = self.steps_per_slab, self.batch_size
         n_replay = int(bsz * self.replay_fraction) if self._rfill else 0
         n_fresh = bsz - n_replay
@@ -131,16 +168,10 @@ class NTTDStreamFitter(StreamFitter):
             rep = rng.integers(0, self._rfill, size=(steps, n_replay))
             pos = np.concatenate([pos, self._rpos[rep]], axis=1)
             val = np.concatenate([val, self._rval[rep]], axis=1)
-        t0 = time.perf_counter()
-        self.params, self._opt_state, loss = self._epoch(
-            self.params,
-            self._opt_state,
-            jnp.asarray(pos, jnp.int32),
-            jnp.asarray(val, jnp.float32),
-        )
-        train_elapsed = time.perf_counter() - t0
+        return idx, vn, rng, pos, val
 
-        # ---- reservoir insert (Algorithm R, vectorized per slab) ----------
+    def _reservoir_insert(self, idx: np.ndarray, vn: np.ndarray, rng) -> None:
+        """Algorithm R over the slab, vectorized."""
         cap = self._rval.shape[0]
         take = min(cap - self._rfill, len(vn))
         if take:
@@ -153,23 +184,6 @@ class NTTDStreamFitter(StreamFitter):
             keep = slots < cap
             self._rpos[slots[keep]] = idx[take:][keep]
             self._rval[slots[keep]] = vn[take:][keep]
-
-        self.entries_seen += len(vn)
-        self.slabs_seen += 1
-        if obs.fit_telemetry_enabled():
-            # float(loss) forces a device sync — only pay it when logging
-            obs.fit_event(
-                "fit_slab",
-                codec="nttd",
-                step=self.slabs_seen - 1,
-                loss=float(loss),
-                entries=len(vn),
-                entries_per_sec=(
-                    len(vn) / train_elapsed if train_elapsed > 0 else None
-                ),
-                reservoir_fill=self._rfill,
-                reservoir_capacity=int(self._rval.shape[0]),
-            )
 
     def _reservoir_orig(self) -> np.ndarray:
         """Reservoir positions mapped back to ORIGINAL indices [fill, d]."""
