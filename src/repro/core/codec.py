@@ -81,17 +81,44 @@ class CompressedTensor:
         """Approximate entries at ORIGINAL indices [B, d] -> [B].
 
         Spans: ``payload.orig_to_pos`` (the host gather), ``nttd.fold``
-        (upload and the eager fold), then ``nttd.apply``'s own, and
+        (upload and the eager fold), ``nttd.operands`` (see
+        ``_decode_operands``), then ``nttd.apply``'s own, and
         ``payload.device_wait`` (the host blocked on the answer)."""
         with obs.span("payload.decode", entries=len(indices)):
             with obs.span("payload.orig_to_pos"):
                 pos = self._orig_to_pos(indices)
             with obs.span("nttd.fold"):
                 folded = self.spec.fold_indices(jnp.asarray(pos, jnp.int32))
-            vals = nttd.apply(self.params, folded, self.spec, self.cfg)
+            vals = nttd.apply(
+                self.params, folded, self.spec, self.cfg,
+                operands=self._decode_operands(),
+            )
             with obs.span("payload.device_wait"):
                 vals = np.asarray(vals)
             return vals * self.norm_std + self.norm_mean
+
+    def _decode_operands(self) -> tuple[jax.Array, ...] | None:
+        """The fused decode's operands (``nttd.fused_decode_inputs``), on
+        the device and stacked once per parameter set; ``None`` where
+        ``nttd.apply`` takes another branch.
+
+        Kept beside the ``params`` object they were built from, outside
+        the dataclass fields, and rebuilt when ``self.params`` is another
+        object (an ``id`` may be reused once the old tree is collected).
+        The ``nttd.operands`` span wraps the lookup; its ``built`` is 1
+        when this call stacked them and 0 when it reused them."""
+        if not nttd.uses_fused_decode(self.spec, self.cfg):
+            return None
+        params = self.params  # one read: the pair stored must match
+        built_from, operands = getattr(self, "_operands", (None, None))
+        built = built_from is not params
+        with obs.span("nttd.operands", built=int(built)):
+            if built:
+                operands = jax.device_put(
+                    nttd.fused_decode_inputs(params, self.spec, self.cfg)
+                )
+                self._operands = (params, operands)
+        return operands
 
     def to_dense(self, batch: int = 65536) -> np.ndarray:
         """Full reconstruction in ORIGINAL index order."""

@@ -109,20 +109,32 @@ def fused_decode_inputs(
     )
 
 
+def uses_fused_decode(spec: FoldingSpec, cfg: NTTDConfig) -> bool:
+    """Whether ``apply`` decodes through the fused kernel, the one branch
+    that takes ``fused_decode_inputs``."""
+    return cfg.kernel_impl == "fused" and spec.d_prime >= 2
+
+
 def apply(
     params: Params,
     folded_idx: jax.Array,  # [B, d'] int32
     spec: FoldingSpec,
     cfg: NTTDConfig,
+    operands: tuple[jax.Array, ...] | None = None,
 ) -> jax.Array:
-    """Approximate entries at the given folded indices.  Returns [B]."""
+    """Approximate entries at the given folded indices.  Returns [B].
+
+    ``operands``, on the fused branch only, is ``fused_decode_inputs`` of
+    ``params`` built beforehand; ``None`` stacks them here (inside a
+    caller's ``jit``, as part of its program)."""
     d_prime = spec.d_prime
     r = cfg.rank
-    if cfg.kernel_impl == "fused" and d_prime >= 2:
+    if uses_fused_decode(spec, cfg):
         # single-program decode: whole chain in one kernel / one XLA program
         # (Pallas on TPU, jitted oracle on CPU — see kernels.ops)
-        with obs.span("nttd.operands"):
-            operands = fused_decode_inputs(params, spec, cfg)
+        if operands is None:
+            with obs.span("nttd.operands", built=1):
+                operands = fused_decode_inputs(params, spec, cfg)
         return ops.nttd_decode_tile(
             folded_idx.astype(jnp.int32), *operands, impl="fused"
         )

@@ -10,8 +10,11 @@ pipeline — ``FleetFrontend.decode_at`` → ``Transport`` wire →
 ``nttd.fold``, ``nttd.operands``, ``payload.device_wait``) → the fused
 ``kernel_decode`` — stitches worker spans back into one cross-process
 trace, and exports Chrome trace-event JSON that Perfetto loads directly.
-``kernel_decode`` times the padding and the enqueue of an asynchronous
-call; the kernel's device time is ``jit_decode_tile`` in a profiler trace.
+``nttd.operands`` wraps the lookup of the fused decode's operands, which
+a payload stacks once per parameter set: its ``built`` is 1 when that
+call stacked them and 0 when it reused them.  ``kernel_decode`` times the
+padding and the enqueue of an asynchronous call; the kernel's device
+time is ``jit_decode_tile`` in a profiler trace.
 The streaming fit records ``fit.update`` with ``fit.sample``,
 ``fit.dispatch`` and ``fit.reservoir`` inside it.  Every live span is
 also a ``jax.profiler.TraceAnnotation``, so a profiler trace holds the
