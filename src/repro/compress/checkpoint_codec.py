@@ -25,9 +25,12 @@ import json
 import os
 from typing import Any
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro import codecs
+from repro import codecs, obs
+from repro.core import nttd
 
 
 @dataclasses.dataclass
@@ -273,16 +276,94 @@ class VersionedCheckpointer:
         self.close()
 
 
+class RestorePlan:
+    """The device restore of a compressed checkpoint's codec leaves.
+
+    Each NTTD leaf is decoded into a flat float32 device buffer of its own
+    in fixed slabs (``CompressedTensor.dense_slabs``), with no index or
+    value crossing the host per slab.  ``steps`` interleaves the slabs of
+    every NTTD leaf, ordered by the fraction of its leaf that a slab
+    completes, so that all leaves advance together.  Leaves of other
+    codecs have no device decode; ``run`` decodes them on the host.
+
+    Spans: ``ckpt.restore`` (``leaves``, ``entries``) around ``run``, and
+    ``ckpt.restore_slab`` (``leaf``, ``entries``, ``d_prime``) around each
+    slab's dispatch.  ``metrics`` counts ``ckpt.restored_entries``.
+    """
+
+    def __init__(self, payload: dict, slab: int = nttd.SLAB_ENTRIES):
+        from repro.codecs.adapters import NTTDEncoded
+
+        self.slabs: dict[str, nttd.DenseSlabs] = {}
+        self.host_leaves: dict[str, tuple[codecs.Encoded, str]] = {}
+        for key, item in payload.items():
+            if item["kind"] == "raw":
+                continue
+            enc = codecs.load_bytes(item["data"])
+            if isinstance(enc, NTTDEncoded):
+                self.slabs[key] = enc.ct.dense_slabs(slab)
+            else:
+                self.host_leaves[key] = (enc, item["dtype"])
+        order = sorted(
+            ((k + 1) / s.n_slabs, i, k, key)
+            for i, (key, s) in enumerate(self.slabs.items())
+            for k in range(s.n_slabs)
+        )
+        self.steps: list[tuple[str, int]] = [(key, k) for _, _, k, key in order]
+        self.buffers: dict[str, jax.Array] = {}
+        self.metrics = obs.MetricsRegistry()
+        self._restored = self.metrics.counter("ckpt.restored_entries")
+
+    @property
+    def entries(self) -> int:
+        """Entries of the NTTD leaves, each counted once."""
+        return sum(s.n for s in self.slabs.values())
+
+    def allocate(self) -> None:
+        """A zeroed float32 device buffer for every NTTD leaf."""
+        self.buffers = {key: jnp.zeros((s.n,), jnp.float32) for key, s in self.slabs.items()}
+
+    def step(self, i: int) -> tuple[str, int]:
+        """Dispatch step ``i`` of the plan (wrapping), asynchronously;
+        returns (leaf, slab)."""
+        key, k = self.steps[i % len(self.steps)]
+        s = self.slabs[key]
+        with obs.span("ckpt.restore_slab", leaf=key, entries=s.entries(k),
+                      d_prime=s.d_prime):
+            self.buffers[key] = s.write(self.buffers[key], k)
+        self._restored.inc(s.entries(k))
+        return key, k
+
+    def run(self) -> dict[str, jax.Array]:
+        """Every codec leaf restored: the NTTD leaves' flat buffers after
+        the whole plan, the other codecs' leaves decoded on the host and
+        put on the device in their own shape and dtype."""
+        with obs.span("ckpt.restore", leaves=len(self.slabs) + len(self.host_leaves),
+                      entries=self.entries):
+            self.allocate()
+            for i in range(len(self.steps)):
+                self.step(i)
+            out = dict(self.buffers)
+            for key, (enc, dtype) in self.host_leaves.items():
+                out[key] = jax.device_put(np.asarray(enc.to_dense()).astype(np.dtype(dtype)))
+        return out
+
+
 def decompress_tree(payload: dict, template):
-    """Inverse of compress_tree (lossy for codec leaves).  The container's
-    codec-id header drives decoding, so `kind` is informational only."""
+    """Inverse of compress_tree (lossy for codec leaves), restored onto the
+    device: raw leaves by ``device_put``, codec leaves by a
+    ``RestorePlan``.  The container's codec-id header drives decoding, so
+    `kind` is informational only."""
     from repro.train.checkpoint import _unflatten_into
 
+    plan = RestorePlan(payload)
+    restored = plan.run()
+    plan.buffers = {}  # each flat buffer goes once its leaf is reshaped
     values = {}
     for key, item in payload.items():
         if item["kind"] == "raw":
-            values[key] = np.load(io.BytesIO(item["data"]))
+            values[key] = jax.device_put(np.load(io.BytesIO(item["data"])))
         else:
-            enc = codecs.load_bytes(item["data"])
-            values[key] = enc.to_dense().astype(np.dtype(item["dtype"]))
+            leaf = restored.pop(key)
+            values[key] = leaf.reshape(item["shape"]).astype(item["dtype"])
     return _unflatten_into(template, values)

@@ -17,6 +17,7 @@ _ARCH_MODULES = {
     "mamba2-1.3b": "mamba2_1_3b",
     "internvl2-76b": "internvl2_76b",
     "musicgen-medium": "musicgen_medium",
+    "granite-4.0-h-small": "granite_4_0_h_small",
     "tensorcodec-paper": "tensorcodec_paper",
 }
 

@@ -21,6 +21,7 @@ class ModelConfig:
     moe_every: int = 1         # every n-th layer has an MoE FFN (1 = all)
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    moe_shared_ff: int = 0     # width of a shared expert beside the routed ones (0 = none)
     # --- SSM / hybrid ---------------------------------------------------------
     ssm_state: int = 0
     ssm_conv: int = 4
@@ -29,9 +30,16 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_chunk: int = 256
     attn_every: int = 1        # hybrid: 1 attention sublayer per n sublayers
+    attn_offset: int = 0       # hybrid: the attention sublayer's index in its period
     # --- misc -------------------------------------------------------------------
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    rope: bool = True          # False: no position embedding (NoPE)
+    # scalars of the Granite 4.0 family; the defaults change nothing
+    embedding_multiplier: float = 1.0  # token embeddings times this
+    residual_multiplier: float = 1.0   # each sublayer's output times this
+    attention_multiplier: float = 0.0  # score scale; 0 = 1/sqrt(head_dim)
+    logits_scaling: float = 1.0        # logits divided by this
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     param_dtype: str = "float32"    # master weights (train); bf16 for serve

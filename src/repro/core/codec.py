@@ -120,17 +120,21 @@ class CompressedTensor:
                 self._operands = (params, operands)
         return operands
 
-    def to_dense(self, batch: int = 65536) -> np.ndarray:
-        """Full reconstruction in ORIGINAL index order."""
-        approx = nttd.generate_tensor(self.params, self.spec, self.cfg, batch)
-        approx = approx * self.norm_std + self.norm_mean
-        return approx[np.ix_(*self.inv_pi)]
+    def dense_slabs(self, slab: int = nttd.SLAB_ENTRIES) -> nttd.DenseSlabs:
+        """The dense decode in ORIGINAL index order, on the device, slab by
+        slab (``nttd.DenseSlabs``)."""
+        return nttd.DenseSlabs(self.params, self.spec, self.cfg, self.inv_pi,
+                               self.norm_mean, self.norm_std, slab)
 
-    def fitness(self, x: np.ndarray, batch: int = 65536) -> float:
-        err = 0.0
-        norm = float(np.linalg.norm(x.astype(np.float64)))
-        approx = self.to_dense(batch)
-        err = float(np.linalg.norm((x - approx).astype(np.float64)))
+    def to_dense(self) -> np.ndarray:
+        """Full reconstruction in ORIGINAL index order, as a host array."""
+        return np.asarray(self.dense_slabs().dense())
+
+    def fitness(self, x: np.ndarray) -> float:
+        """1 - ||x - approx|| / ||x||, the squared error summed on the
+        device slab by slab (the approximation never reaches the host)."""
+        norm = float(np.linalg.norm(np.asarray(x, np.float64)))
+        err = np.sqrt(self.dense_slabs().sq_err(x))
         return 1.0 - err / max(norm, 1e-30)
 
     def _orig_to_pos(self, indices: np.ndarray) -> np.ndarray:

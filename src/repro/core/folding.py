@@ -92,6 +92,23 @@ class FoldingSpec:
         digits = (idx[..., :, None] // self.strides) % self.factors
         return xp.sum(digits * self.fstrides, axis=-2)
 
+    def fold_modes(self, modes):
+        """``fold_indices`` of d index vectors ([B] each, original mode
+        order) -> [B, d'] int32, as digit arithmetic by the spec's own
+        factors: a chain of scalar-constant ops, which XLA compiles far
+        faster than the [B, d, d'] broadcast at large B."""
+        folded = [0] * self.d_prime
+        for k, idx in enumerate(modes):
+            for j in reversed(range(self.d_prime)):
+                f = int(self.factors[k, j])
+                if f > 1:
+                    folded[j] = folded[j] + (idx % f) * int(self.fstrides[k, j])
+                    idx = idx // f
+        b = jnp.shape(modes[0])
+        return jnp.stack(
+            [jnp.broadcast_to(jnp.asarray(v, jnp.int32), b) for v in folded], axis=-1
+        )
+
     def unfold_indices(self, fidx):
         """[..., d'] folded indices -> [..., d] original indices.
 
@@ -107,8 +124,16 @@ def make_folding_spec(shape: Sequence[int], d_prime: int | None = None) -> Foldi
     shape = tuple(int(s) for s in shape)
     if d_prime is None:
         d_prime = default_d_prime(shape)
-    d = len(shape)
     factors = np.array([choose_factors(n, d_prime) for n in shape], dtype=np.int64)
+    return spec_from_factors(shape, factors)
+
+
+def spec_from_factors(shape: Sequence[int], factors) -> FoldingSpec:
+    """The spec of an explicit [d, d'] factor matrix (a stored payload's,
+    or ``make_folding_spec``'s own choice)."""
+    shape = tuple(int(s) for s in shape)
+    factors = np.asarray(factors, dtype=np.int64)
+    d, d_prime = factors.shape
     strides = np.ones((d, d_prime), dtype=np.int64)
     for j in range(d_prime - 2, -1, -1):
         strides[:, j] = strides[:, j + 1] * factors[:, j + 1]

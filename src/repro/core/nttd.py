@@ -13,6 +13,7 @@ friendly (folded indices in, scalar approximations out).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.folding import FoldingSpec
+from repro.core.folding import FoldingSpec, spec_from_factors
 from repro.kernels import ops
 
 Params = dict[str, Any]
@@ -185,29 +186,121 @@ def make_predict(spec: FoldingSpec, cfg: NTTDConfig):
 # historical call sites (and external users) that import it from nttd
 from repro.codecs.indexing import flat_to_multi  # noqa: E402, F401
 
+#: entries of one slab of a dense decode: fixed, so that each tensor shape
+#: compiles one slab program
+SLAB_ENTRIES = 1 << 22
+
+
+def _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab):
+    """Entries ``[start, start + slab)`` of the original row-major order:
+    flat indices from an iota, unravelled, each mode mapped to its
+    position through ``inv_pi`` (gathered on the device), folded, decoded
+    through the fused tile and de-normalised.  [slab] float32."""
+    rem = start + jnp.arange(slab, dtype=jnp.int32)
+    pos = [None] * len(shape)
+    for k in reversed(range(len(shape))):
+        pos[k] = jnp.take(inv_pi[k], rem % shape[k])
+        rem = rem // shape[k]
+    folded = spec_from_factors(shape, factors).fold_modes(pos)
+    vals = ops.nttd_decode_tile(folded, *operands, impl="fused")
+    return vals.astype(jnp.float32) * std + mean
+
+
+@functools.partial(
+    jax.jit, static_argnames=("shape", "factors", "slab"), donate_argnums=(0,)
+)
+def restore_slab(buf, start, operands, inv_pi, mean, std, *, shape, factors, slab):
+    """``buf`` (flat float32, donated) with one slab of the dense decode
+    written at ``start``."""
+    vals = _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab)
+    return jax.lax.dynamic_update_slice(buf, vals.astype(buf.dtype), (start,))
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "factors", "slab"))
+def slab_sq_err(x, first, start, operands, inv_pi, mean, std, *, shape, factors, slab):
+    """Summed squared error of one slab of the dense decode against ``x``
+    (the same slab of the original tensor), over entries from ``first``."""
+    vals = _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab)
+    flat = start + jnp.arange(slab, dtype=jnp.int32)
+    return jnp.sum(jnp.where(flat >= first, jnp.square(x - vals), 0.0))
+
+
+class DenseSlabs:
+    """A tensor's dense decode on the device, in fixed slabs of its
+    original row-major order.  No index or value crosses the host per
+    slab: the operands, the per-mode inverse orders and the normalisation
+    are uploaded once.  The decode is the fused tile's, whatever kernel
+    ``cfg`` names (on a TPU the tile is the decode path).
+
+    Slab ``k`` holds entries ``[k * slab, (k + 1) * slab)``; the last one
+    is shifted back to end at the tensor's end, so it overlaps the slab
+    before it and every slab has one shape."""
+
+    def __init__(self, params: Params, spec: FoldingSpec, cfg: NTTDConfig,
+                 inv_pi=None, mean: float = 0.0, std: float = 1.0,
+                 slab: int = SLAB_ENTRIES):
+        n = spec.n_entries
+        if n >= 2**31:
+            raise ValueError(f"{n} entries overflow int32 flat indices")
+        if spec.d_prime < 2:
+            raise ValueError(f"the fused decode needs d' >= 2, got {spec.d_prime}")
+        self.shape = tuple(spec.shape)
+        self.d_prime = spec.d_prime
+        self.n = n
+        self.slab = min(int(slab), n)
+        self.n_slabs = -(-n // self.slab)
+        if inv_pi is None:
+            inv_pi = [np.arange(m) for m in self.shape]
+        self._static = {
+            "shape": self.shape,
+            "factors": tuple(tuple(int(f) for f in row) for row in spec.factors),
+            "slab": self.slab,
+        }
+        self._args = jax.device_put((
+            fused_decode_inputs(params, spec, cfg),
+            tuple(jnp.asarray(p, jnp.int32) for p in inv_pi),
+            jnp.float32(mean),
+            jnp.float32(std),
+        ))
+
+    def start(self, k: int) -> int:
+        return min(k * self.slab, self.n - self.slab)
+
+    def entries(self, k: int) -> int:
+        """Entries that slab ``k`` adds (the tail's overlap not counted)."""
+        return min(self.slab, self.n - k * self.slab)
+
+    def write(self, buf: jax.Array, k: int) -> jax.Array:
+        """``buf`` (flat float32 of the tensor's size, donated) with slab
+        ``k`` written; asynchronous."""
+        return restore_slab(buf, jnp.int32(self.start(k)), *self._args, **self._static)
+
+    def dense(self) -> jax.Array:
+        """The whole decode, on the device, in the tensor's shape."""
+        buf = jnp.zeros((self.n,), jnp.float32)
+        for k in range(self.n_slabs):
+            buf = self.write(buf, k)
+        return buf.reshape(self.shape)
+
+    def sq_err(self, x: np.ndarray) -> float:
+        """Summed squared error of the decode against ``x`` (original
+        order, any shape of the tensor's size), slab by slab on the device;
+        each slab of ``x`` is uploaded once."""
+        flat = np.asarray(x, np.float32).reshape(-1)
+        total = jnp.zeros((), jnp.float32)
+        for k in range(self.n_slabs):
+            s = self.start(k)
+            total = total + slab_sq_err(
+                jnp.asarray(flat[s : s + self.slab]), jnp.int32(k * self.slab),
+                jnp.int32(s), *self._args, **self._static,
+            )
+        return float(total)
+
 
 def generate_tensor(
-    params: Params,
-    spec: FoldingSpec,
-    cfg: NTTDConfig,
-    batch: int = 65536,
-    predict_fn=None,
+    params: Params, spec: FoldingSpec, cfg: NTTDConfig, slab: int = SLAB_ENTRIES
 ) -> np.ndarray:
-    """Materialize the full approximated tensor (reordered coordinates).
-
-    Used for fitness evaluation on small/medium tensors and for the
-    expressiveness experiment (Fig. 8).
-    """
-    n = spec.n_entries
-    out = np.empty((n,), dtype=np.float32)
-    fn = predict_fn or make_predict(spec, cfg)
-    # fixed batch (pad the tail) so the jitted fn compiles exactly once
-    for start in range(0, n, batch):
-        stop = min(start + batch, n)
-        flat = np.arange(start, stop, dtype=np.int64)
-        if stop - start < batch:
-            flat = np.pad(flat, (0, batch - (stop - start)))
-        pos = flat_to_multi(flat, spec.shape)
-        got = np.asarray(fn(params, jnp.asarray(pos, jnp.int32)))
-        out[start:stop] = got[: stop - start]
-    return out.reshape(spec.shape)
+    """Materialize the full approximated tensor (reordered coordinates),
+    decoded on the device in slabs (``DenseSlabs``).  Used by the
+    expressiveness experiment (Fig. 8)."""
+    return np.asarray(DenseSlabs(params, spec, cfg, slab=slab).dense())

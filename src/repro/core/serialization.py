@@ -28,7 +28,7 @@ import numpy as np
 
 from repro.core import codec as codec_mod
 from repro.core import nttd
-from repro.core.folding import FoldingSpec
+from repro.core.folding import spec_from_factors
 
 MAGIC = b"TCDC"
 VERSION = 2
@@ -124,8 +124,6 @@ def load_bytes(
     """
     import jax
 
-    from repro.core.folding import make_folding_spec
-
     buf = io.BytesIO(data)
     if buf.read(4) != MAGIC:
         raise ValueError("not a TensorCodec payload")
@@ -136,10 +134,8 @@ def load_bytes(
         raise ValueError(f"unsupported version {version}")
     shape = tuple(np.frombuffer(buf.read(8 * d), dtype=np.uint64).astype(int))
     factors = np.frombuffer(buf.read(d * d_prime), dtype=np.uint8).reshape(d, d_prime)
-    spec = make_folding_spec(shape, d_prime)
-    if not np.array_equal(spec.factors, factors.astype(np.int64)):
-        # factor chooser changed between versions: rebuild spec from factors
-        spec = _spec_from_factors(shape, factors.astype(np.int64))
+    # the stored factors, not today's chooser, define the payload's folding
+    spec = spec_from_factors(shape, factors)
     cfg = nttd.NTTDConfig(
         rank=rank,
         hidden=hidden,
@@ -168,23 +164,6 @@ def _fill(template, buf: io.BytesIO, dtype):
     n = int(np.prod(template.shape))
     raw = np.frombuffer(buf.read(n * np.dtype(dtype).itemsize), dtype=dtype)
     return jnp.asarray(raw.reshape(template.shape), template.dtype)
-
-
-def _spec_from_factors(shape, factors: np.ndarray) -> FoldingSpec:
-    d, d_prime = factors.shape
-    strides = np.ones((d, d_prime), dtype=np.int64)
-    for j in range(d_prime - 2, -1, -1):
-        strides[:, j] = strides[:, j + 1] * factors[:, j + 1]
-    fstrides = np.ones((d, d_prime), dtype=np.int64)
-    for k in range(d - 2, -1, -1):
-        fstrides[k, :] = fstrides[k + 1, :] * factors[k + 1, :]
-    return FoldingSpec(
-        shape=tuple(int(s) for s in shape),
-        factors=factors,
-        strides=strides,
-        fstrides=fstrides,
-        folded_shape=tuple(int(x) for x in factors.prod(axis=0)),
-    )
 
 
 def save_file(path: str, ct: codec_mod.CompressedTensor, dtype=np.float32) -> int:

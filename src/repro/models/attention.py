@@ -1,4 +1,6 @@
-"""GQA attention with RoPE: training, prefill (cache write), decode.
+"""GQA attention with RoPE (or none, ``cfg.rope=False``): training,
+prefill (cache write), decode.  ``cfg.attention_multiplier``, when set,
+scales the scores in place of 1/sqrt(head_dim).
 
 KV caches have logical axes (batch, long_kv/kv_seq, kv_heads, head_dim);
 the long-context rules map the cache length onto the 'data' mesh axis when
@@ -40,8 +42,12 @@ def _qkv(p: dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array, dt):
         q = q + p["bq"].astype(dt)
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
-    q = layers.rope(q, positions, cfg.rope_theta)
-    k = layers.rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = layers.rope(q, positions, cfg.rope_theta)
+        k = layers.rope(k, positions, cfg.rope_theta)
+    if cfg.attention_multiplier:
+        # the kernels scale scores by 1/sqrt(head_dim); undo it in q
+        q = q * jnp.asarray(cfg.attention_multiplier * q.shape[-1] ** 0.5, dt)
     # 'seq_attn' is None by default; rules map it to 'model' for archs
     # whose head count cannot take the TP axis (context-parallel attention)
     q = shard(q, "batch", "seq_attn", "heads", "head_dim")

@@ -14,6 +14,7 @@
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist import sharding
@@ -66,7 +67,16 @@ def _embed_in(params, cfg: ModelConfig, tokens, embeds):
         x = embeds.astype(dt)
     else:
         x = layers.embed_lookup(params["tok"], tokens, dt)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, dt)
     return shard(x, "batch", "seq", "act_embed")
+
+
+def _logits(params, cfg: ModelConfig, x):
+    logits = layers.unembed(params["tok"], x, layers.dtype_of(cfg.compute_dtype))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+    return logits
 
 
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None):
@@ -74,7 +84,7 @@ def forward(params, cfg: ModelConfig, tokens=None, embeds=None):
     x = _embed_in(params, cfg, tokens, embeds)
     x, _, aux = transformer.run_stack(params, x, cfg, mode="full")
     x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return layers.unembed(params["tok"], x, layers.dtype_of(cfg.compute_dtype)), aux
+    return _logits(params, cfg, x), aux
 
 
 def loss_fn(params, cfg: ModelConfig, batch: dict):
@@ -92,8 +102,7 @@ def prefill(params, cfg: ModelConfig, tokens=None, cache=None, embeds=None):
     x = _embed_in(params, cfg, tokens, embeds)
     x, new_cache, _ = transformer.run_stack(params, x, cfg, cache=cache, mode="prefill")
     x = layers.rmsnorm(x[:, -1:, :], params["final_norm"], cfg.norm_eps)
-    logits = layers.unembed(params["tok"], x, layers.dtype_of(cfg.compute_dtype))
-    return logits, new_cache
+    return _logits(params, cfg, x), new_cache
 
 
 def decode_step(params, cfg: ModelConfig, token=None, cache=None, cache_len=None,
@@ -105,8 +114,7 @@ def decode_step(params, cfg: ModelConfig, token=None, cache=None, cache_len=None
         params, x, cfg, cache=cache, cache_len=cache_len, mode="decode"
     )
     x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = layers.unembed(params["tok"], x, layers.dtype_of(cfg.compute_dtype))
-    return logits, new_cache
+    return _logits(params, cfg, x), new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,9 @@ Out-of-capacity slots scatter out of bounds and are dropped
 Decode (S == 1): each group is a single token whose k routed experts are
 distinct, so C = k guarantees zero drops and decode stays bit-consistent
 with teacher forcing.
+
+A shared expert (``cfg.moe_shared_ff``) sees every token; its output is
+added to the routed experts'.
 """
 from __future__ import annotations
 
@@ -27,12 +30,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import ParamSpec, shard
+from repro.models import layers
 
 
 def moe_specs(cfg: ModelConfig, stacked: tuple[int, ...] = ()) -> dict:
     d, f, e = cfg.d_model, cfg.d_ff, cfg.moe_experts
     lead = tuple("layers" for _ in stacked)
-    return {
+    specs = {
         "router": ParamSpec(stacked + (d, e), lead + ("ffn_in", "experts")),
         "w_gate": ParamSpec(
             stacked + (e, d, f), lead + ("experts", "expert_in", "expert_mlp")
@@ -44,6 +48,9 @@ def moe_specs(cfg: ModelConfig, stacked: tuple[int, ...] = ()) -> dict:
             stacked + (e, f, d), lead + ("experts", "expert_mlp", "expert_in")
         ),
     }
+    if cfg.moe_shared_ff:
+        specs["shared"] = layers.mlp_specs(d, cfg.moe_shared_ff, stacked)
+    return specs
 
 
 def group_capacity(group_tokens: int, cfg: ModelConfig) -> int:
@@ -123,4 +130,6 @@ def moe_ffn(p: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Arr
     y_s = y_s * (gates_s * in_cap)[:, :, None].astype(dt)
     y = jnp.zeros((g, s, d), dt).at[gidx, tok_s].add(y_s)
     y = y.reshape(b, s, d)
+    if cfg.moe_shared_ff:
+        y = y + layers.mlp(p["shared"], x, dt)
     return shard(y, "batch", "seq", "act_embed"), aux

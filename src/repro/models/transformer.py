@@ -7,7 +7,10 @@ A *block* is the scan unit; each family defines a block layout — a list of
   moe e1   : [(attn, moe)]                                x n_layers   (grok)
   moe e2   : [(attn, mlp), (attn, moe)]                   x n_layers/2 (llama4)
   hybrid   : [(attn, mlp|moe), (mamba, ...) x 7]          x n_layers/8 (jamba,
-             1 attention per 8 sublayers, MoE on odd global layer indices)
+             1 attention per 8 sublayers, MoE on odd global layer indices);
+             attention at sublayer ``attn_offset`` of its period, MoE on
+             the last sublayer of every ``moe_every`` (granite-4.0-h:
+             attention at 5 of 10, MoE on all)
   ssm      : [(mamba, None)]                              x n_layers   (mamba2)
 
 Within a block, params of each sublayer type are stacked on a 'sublayers'
@@ -44,8 +47,9 @@ def block_layout(cfg: ModelConfig) -> list[tuple[str, str | None]]:
     if cfg.family == "hybrid":
         out = []
         for i in range(cfg.attn_every):
-            mixer = "attn" if i == 0 else "mamba"
-            ffn = "moe" if (cfg.moe_experts and i % 2 == 1) else "mlp"
+            mixer = "attn" if i == cfg.attn_offset else "mamba"
+            moe_here = cfg.moe_experts and i % cfg.moe_every == cfg.moe_every - 1
+            ffn = "moe" if moe_here else "mlp"
             out.append((mixer, ffn))
         return out
     if cfg.family == "ssm":
@@ -124,6 +128,12 @@ def _tree_index(tree, i: int):
     return jax.tree.map(lambda a: a[i], tree)
 
 
+def _residual(y: jax.Array, cfg: ModelConfig) -> jax.Array:
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+
+
 def apply_block(
     bp: dict,
     x: jax.Array,
@@ -161,7 +171,7 @@ def apply_block(
             if mode in ("prefill", "decode"):
                 mamba_caches.append(nst)
         idx[mixer] += 1
-        x = x + y
+        x = x + _residual(y, cfg)
         x = shard(x, "batch", "seq", "act_embed")
 
         if ffn:
@@ -177,7 +187,7 @@ def apply_block(
                 y, a = moe.moe_ffn(_tree_index(bp["moe"], idx["moe"]), h, cfg)
                 aux = aux + a
             idx[ffn] += 1
-            x = x + y
+            x = x + _residual(y, cfg)
             x = shard(x, "batch", "seq", "act_embed")
 
     if mode == "full":

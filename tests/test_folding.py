@@ -3,6 +3,7 @@
 hypothesis is unavailable offline; properties are checked over seeded
 randomized shape grids (same invariants, deterministic).
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -72,3 +73,13 @@ def test_dprime_exceeds_order():
     for shape in SHAPES:
         spec = make_folding_spec(shape)
         assert spec.d_prime > len(shape)  # paper: d' > d
+
+
+@pytest.mark.parametrize("shape", [(7, 1, 30), (100, 37), (1, 9, 64, 131)])
+def test_fold_modes_matches_fold_indices(shape):
+    spec = make_folding_spec(shape)
+    rng = np.random.default_rng(0)
+    idx = np.stack([rng.integers(0, n, 257) for n in shape], axis=1)
+    got = spec.fold_modes([jnp.asarray(idx[:, k], jnp.int32) for k in range(len(shape))])
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), spec.fold_indices(idx))

@@ -94,6 +94,7 @@ def test_full_config_abstract_params(arch):
         "qwen1.5-4b": 4e9, "grok-1-314b": 314e9,
         "llama4-maverick-400b-a17b": 400e9, "jamba-1.5-large-398b": 398e9,
         "mamba2-1.3b": 1.3e9, "internvl2-76b": 70e9, "musicgen-medium": 1.5e9,
+        "granite-4.0-h-small": 32e9,
     }[arch]
     assert 0.5 * expected_ballpark < n < 2.2 * expected_ballpark, (arch, n)
 

@@ -1,5 +1,7 @@
 """MoE dispatch invariants."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,3 +76,22 @@ def test_aux_loss_uniform_router_is_one():
     _, aux = moe.moe_ffn(p, x, cfg)
     # me = 1/E exactly; ce depends on top-1 tie-breaking; aux = E*sum(me*ce) = 1
     np.testing.assert_allclose(float(aux), 1.0, rtol=1e-5)
+
+
+def test_the_shared_expert_adds_to_the_routed_experts():
+    """A shared expert sees every token and adds its MLP's output to the
+    routed experts'; the router and the routed experts are unchanged."""
+    from repro.models import layers
+
+    cfg = _cfg(moe_experts=8, moe_top_k=3, moe_shared_ff=48, moe_capacity_factor=16.0)
+    p = _params(cfg, jax.random.PRNGKey(0))
+    assert moe.moe_specs(cfg)["shared"]["w_up"].shape == (cfg.d_model, 48)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.d_model))
+    y, aux = moe.moe_ffn(p, x, cfg)
+    routed = dataclasses.replace(cfg, moe_shared_ff=0)
+    assert set(moe.moe_specs(routed)) == set(p) - {"shared"}
+    y0, aux0 = moe.moe_ffn({k: v for k, v in p.items() if k != "shared"}, x, routed)
+    shared = layers.mlp(p["shared"], x, jnp.float32)
+    assert float(jnp.abs(shared).max()) > 0
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0 + shared), rtol=1e-5, atol=1e-5)
+    assert float(aux) == float(aux0)

@@ -81,7 +81,7 @@ def test_count_params_matches_theorem1_structure():
 
 def test_generate_tensor_matches_pointwise():
     spec, cfg, params = _setup(shape=(6, 5, 4))
-    full = nttd.generate_tensor(params, spec, cfg, batch=64)
+    full = nttd.generate_tensor(params, spec, cfg, slab=64)
     rng = np.random.default_rng(3)
     pos = np.stack([rng.integers(0, n, 32) for n in spec.shape], axis=1)
     vals = nttd.apply_at_positions(params, jnp.asarray(pos, jnp.int32), spec, cfg)
