@@ -167,14 +167,16 @@ def nttd_decode_tile(
 ) -> jax.Array:
     """Fused NTTD decode of a [B, T] tile of folded indices -> [B] values.
 
-    See ``decode_tile.decode_tile`` for operand layout.  Batch padding to
-    the Pallas tile is handled here; B == 0 short-circuits (a zero-size
-    grid is invalid in Pallas).
+    See ``decode_tile.decode_tile`` for the operands; the kernel itself
+    runs batch-on-lanes (``[T, B]`` indices in, a lane-dense ``[1, B]``
+    out), and its program pads and transposes.  B == 0 short-circuits (a
+    zero-size grid is invalid in Pallas).
 
-    The ``kernel_decode`` span times the padding and the enqueue of an
-    asynchronous call, not the kernel: the kernel's device time is
-    ``jit_decode_tile`` in a profiler trace.  ``b`` and ``padded`` count
-    the rows asked for and the rows the kernel runs.
+    The ``kernel_decode`` span times the enqueue of an asynchronous call,
+    not the kernel: the kernel's device time is ``jit_decode_tile`` in a
+    profiler trace.  ``b`` and ``padded`` count the rows asked for and
+    the rows the kernel runs, ``tile`` the lanes of one grid step
+    (``decode_tile.tile_width``; the whole batch for the oracles).
     """
     bsz = idx.shape[0]
     if bsz == 0:
@@ -182,16 +184,17 @@ def nttd_decode_tile(
     if impl in ("auto", "fused"):
         impl = "pallas" if jax.default_backend() == "tpu" else "fused"
     heads = (w_first, b_first, w_mid, b_mid, w_last, b_last)
-    tile = tile_b or min(_dt.DEFAULT_TILE_B, max(8, bsz))
-    padded = bsz if impl in ("ref", "fused") else bsz + (-bsz) % tile
-    with obs.span("kernel_decode", impl=impl, b=bsz, padded=padded):
+    if impl in ("ref", "fused"):
+        tile = padded = bsz
+    else:
+        tile = _dt.tile_width(bsz, emb.shape[2], b_first.shape[0], tile_b)
+        padded = bsz + (-bsz) % tile
+    with obs.span("kernel_decode", impl=impl, b=bsz, padded=padded, tile=tile):
         if impl == "ref":
             return _ref.nttd_decode_tile(idx, emb, wi, wh, b, *heads)
         if impl == "fused":
             return _fused_oracle(idx, emb, wi, wh, b, *heads)
-        idx_p, _ = _pad_batch(idx, tile)
-        out = _dt.decode_tile(
-            idx_p, emb, wi, wh, b, *heads,
+        return _dt.decode_tile(
+            idx, emb, wi, wh, b, *heads,
             tile_b=tile, interpret=impl == "pallas_interpret",
         )
-        return out[:bsz]

@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_tile import LANES, lane_operands
+
 
 # ----------------------------------------------------------------------------
 # TT-core chain contraction (NTTD, Alg. 2 line 8)
@@ -130,45 +132,52 @@ def nttd_decode_tile(
     embedding tables (padded to M rows); heads as in decode_tile.
     Returns [B] in ``emb.dtype``.
 
-    All math is f32 internally (matching the kernel), with the chain
-    contracted step-interleaved in the exact order the kernel uses so
-    interpret-mode parity is bitwise, not merely close.
+    All math is f32 internally (matching the kernel), in the kernel's
+    batch-on-lanes layout and grouping (``decode_tile.lane_operands``:
+    the batch on the minor axis and padded to whole 128-lane columns,
+    hidden units and core rows zero-padded to a multiple of 8, the input
+    gates of every table row gathered, ``h``'s dots stacked) with the
+    chain contracted step-interleaved in the exact order the kernel uses,
+    so interpret-mode parity is bitwise, not merely close: XLA:CPU sums a
+    dot in one order for every width of whole columns.
     """
     bsz, t_steps = idx.shape
     if t_steps < 2:
         raise ValueError(f"nttd_decode_tile needs T >= 2 steps, got {t_steps}")
     rank = b_first.shape[0]
-    hid = emb.shape[-1]
-    embf = emb.astype(jnp.float32)
-    wif = wi.astype(jnp.float32)
-    whf = wh.astype(jnp.float32)
-    bf = b.astype(jnp.float32)
-    h = jnp.zeros((bsz, hid), jnp.float32)
-    c = jnp.zeros((bsz, hid), jnp.float32)
-    v = None
-    out = None
+    gx, b, wf, bf, wm, bm, wl, bl = lane_operands(
+        emb, wi, wh, b, w_first, b_first, w_mid, b_mid, w_last, b_last)
+    g4 = b.shape[0]
+    hp, rp = g4 // 4, wl.shape[0]
+    idx = jnp.pad(idx, ((0, (-bsz) % LANES), (0, 0)))
+    c = jnp.zeros((hp, idx.shape[0]), jnp.float32)
+    rec = v = out = None
     for t in range(t_steps):
-        xt = jnp.take(embf[t], idx[:, t], axis=0)  # [B, H]
-        gates = xt @ wif + h @ whf + bf
-        i = jax.nn.sigmoid(gates[:, :hid])
-        f = jax.nn.sigmoid(gates[:, hid : 2 * hid])
-        g = jnp.tanh(gates[:, 2 * hid : 3 * hid])
-        o = jax.nn.sigmoid(gates[:, 3 * hid :])
+        parts = jnp.take(gx[t], idx[:, t], axis=1).astype(jnp.float32)
+        gates = parts[:g4] + parts[g4 : 2 * g4] + parts[2 * g4 :]  # [4Hp, B]
+        if rec is not None:
+            gates = gates + rec
+        gates = gates + b
+        i = jax.nn.sigmoid(gates[:hp])
+        f = jax.nn.sigmoid(gates[hp : 2 * hp])
+        g = jnp.tanh(gates[2 * hp : 3 * hp])
+        o = jax.nn.sigmoid(gates[3 * hp :])
         c = f * c + i * g
         h = o * jnp.tanh(c)
-        if t == 0:
-            v = h @ w_first.astype(jnp.float32) + b_first.astype(jnp.float32)
-        elif t == t_steps - 1:
-            last = h @ w_last.astype(jnp.float32) + b_last.astype(jnp.float32)
-            out = jnp.sum(v * last, axis=-1)
+        if t == t_steps - 1:
+            out = jnp.sum(v * (wl @ h + bl), axis=0)
+        elif t == 0:
+            z = wf @ h
+            rec, v = z[:g4], z[g4:] + bf
         else:
-            mid = h @ w_mid.astype(jnp.float32) + b_mid.astype(jnp.float32)
-            # v[b, s] = sum_r v[b, r] * mid[b, r*R + s], summed over r in order
-            acc = v[:, 0:1] * mid[:, 0:rank]
+            z = wm @ h
+            rec, mid = z[:g4], z[g4:] + bm
+            # v[s] = sum_r v[r] * mid[r*Rp + s], summed over r in order
+            acc = v[0:1] * mid[0:rp]
             for r in range(1, rank):
-                acc = acc + v[:, r : r + 1] * mid[:, r * rank : (r + 1) * rank]
+                acc = acc + v[r : r + 1] * mid[r * rp : (r + 1) * rp]
             v = acc
-    return out.astype(emb.dtype)
+    return out[:bsz].astype(emb.dtype)
 
 
 # ----------------------------------------------------------------------------

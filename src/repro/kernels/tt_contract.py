@@ -6,7 +6,7 @@ column.  R is small (4..32), so a 128x128 MXU pass would be >94% idle —
 this is restructured as a *lane-parallel batched matvec*: the batch is
 tiled into VMEM blocks of TILE_B rows (sublane axis), and the per-step
 contraction v[b,s] = sum_r v[b,r] * M[b,k,r,s] is an unrolled VPU
-multiply-accumulate over the tiny R axis (``decode_tile.chain_step``).
+multiply-accumulate over the tiny R axis (``chain_step``).
 
 HBM traffic: each core tensor is read exactly once; the running vector
 stays in registers/VMEM across all K steps (the fusion the XLA path
@@ -20,9 +20,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.decode_tile import chain_step
-
 DEFAULT_TILE_B = 256
+
+
+def chain_step(v: jax.Array, mid: jax.Array, rank: int) -> jax.Array:
+    """``v[b, s] = sum_r v[b, r] * mid[b, r*R + s]``: one link of the TT
+    chain as R lane slices multiply-accumulated on the VPU (R is tiny).
+    Mosaic refuses the [TB, R*R] -> [TB, R, R] reshape, so the R x R core
+    stays flat."""
+    acc = v[:, 0:1] * mid[:, 0:rank]
+    for r in range(1, rank):
+        acc = acc + v[:, r : r + 1] * mid[:, r * rank : (r + 1) * rank]
+    return acc
 
 
 def _kernel(first_ref, mid_ref, last_ref, out_ref, *, k_steps: int, rank: int):

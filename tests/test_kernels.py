@@ -205,6 +205,68 @@ def test_decode_tile_non_multiple_batch():
     assert np.array_equal(np.asarray(got), np.asarray(fused))
 
 
+# the benchmark's widths: (H, R, M, T) of pems MEDIUM, nyc SMALL and the
+# widest granite leaf (d' 17)
+BENCH_WIDTHS = {"pems": (18, 10, 8, 10), "nyc": (12, 6, 16, 9), "granite": (16, 8, 16, 17)}
+
+
+def _per_op_chain(idx, ws):
+    """The multi-op decode (``nttd.apply`` with ``kernel_impl="ref"``):
+    gather, ``lstm_scan``, the three heads, ``tt_contract``."""
+    emb, wi, wh, b, wf, bf, wm, bm, wl, bl = ws
+    t, rank = idx.shape[1], bf.shape[0]
+    x = jnp.stack([emb[j][idx[:, j]] for j in range(t)], axis=1)
+    hs = ops.lstm_scan(x, wi, wh, b, impl="ref")
+    mids = (hs[:, 1:-1] @ wm + bm).reshape(-1, t - 2, rank, rank)
+    return ops.tt_contract(hs[:, 0] @ wf + bf, mids, hs[:, -1] @ wl + bl, impl="ref")
+
+
+@pytest.mark.parametrize("batch", [1, 127, 300, 1000])
+@pytest.mark.parametrize("widths", list(BENCH_WIDTHS), ids=str)
+def test_decode_tile_at_bench_widths(widths, batch):
+    """At each configuration's widths, batches that are not a multiple of
+    128 lanes, with the tile width the wrapper picks: interpret mode is
+    bit-identical to the jitted oracle and close to the per-op chain."""
+    hid, rank, m, t = BENCH_WIDTHS[widths]
+    idx, ws = _decode_tile_args(batch, t, m, hid, rank, jnp.float32)
+    got = ops.nttd_decode_tile(idx, *ws, impl="pallas_interpret")
+    fused = ops.nttd_decode_tile(idx, *ws, impl="fused")
+    assert got.shape == (batch,)
+    assert np.array_equal(np.asarray(got), np.asarray(fused))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_per_op_chain(idx, ws)),
+                               rtol=1e-5, atol=1e-5)
+
+
+def _pad_hidden(ws, hp):
+    """The operands with zero hidden units appended up to ``hp``: zero
+    embedding columns, zero gate rows and columns in each gate block, zero
+    head rows."""
+    emb, wi, wh, b, wf, bf, wm, bm, wl, bl = ws
+    dh = hp - emb.shape[2]
+
+    def gates(w):
+        w = w.reshape(w.shape[0], 4, -1)
+        return jnp.pad(w, ((0, dh), (0, 0), (0, dh))).reshape(hp, 4 * hp)
+
+    rows = lambda w: jnp.pad(w, ((0, dh), (0, 0)))  # noqa: E731
+    return (jnp.pad(emb, ((0, 0), (0, 0), (0, dh))), gates(wi), gates(wh),
+            jnp.pad(b.reshape(4, -1), ((0, 0), (0, dh))).reshape(4 * hp),
+            rows(wf), bf, rows(wm), bm, rows(wl), bl)
+
+
+@pytest.mark.parametrize("widths", ["pems", "nyc"])
+def test_decode_tile_padded_hidden_matches_unpadded(widths):
+    """Hidden units padded with zeros to a multiple of 8 (pems 18 -> 24,
+    nyc 12 -> 16) decode exactly what the unpadded operands decode: a
+    padded unit's c and h stay 0."""
+    hid, rank, m, t = BENCH_WIDTHS[widths]
+    idx, ws = _decode_tile_args(300, t, m, hid, rank, jnp.float32)
+    got = ops.nttd_decode_tile(idx, *_pad_hidden(ws, -(-hid // 8) * 8),
+                               impl="pallas_interpret")
+    assert np.array_equal(np.asarray(got),
+                          np.asarray(ops.nttd_decode_tile(idx, *ws, impl="fused")))
+
+
 def test_decode_tile_empty_batch():
     idx, ws = _decode_tile_args(0, 3, 7, 16, 8, jnp.float32)
     for impl in ("pallas_interpret", "fused", "ref", "auto"):

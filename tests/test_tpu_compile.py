@@ -13,6 +13,7 @@ test worker imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,31 +51,65 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _fused_operands(rank, hidden, shape=(963, 144, 440)):
+# granite's widest leaf (``tok/embed``): d' 17 at rank 8, hidden 16
+GRANITE_EMBED = ((100352, 4096), 17)
+
+
+def _fused_operands(rank, hidden, shape=(963, 144, 440), d_prime=None):
     from repro.core import nttd
     from repro.core.folding import make_folding_spec
 
-    spec = make_folding_spec(shape)
+    spec = make_folding_spec(shape, d_prime)
     cfg = nttd.NTTDConfig(rank=rank, hidden=hidden)
     params = jax.eval_shape(lambda k: nttd.init_params(k, spec, cfg), jax.random.PRNGKey(0))
     ops = jax.eval_shape(lambda p: nttd.fused_decode_inputs(p, spec, cfg), params)
     return spec, ops
 
 
+def _has_decode_tile(compiled) -> bool:
+    """The kernel is there under the name both roofline readers key on: a
+    ``tpu_custom_call`` instruction named ``decode_tile``."""
+    return re.search(r"%decode_tile(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"",
+                     compiled.as_text()) is not None
+
+
 @pytest.mark.parametrize("batch", [1, 100, 256, 300, 4096])
-@pytest.mark.parametrize("rank,hidden", [(6, 12), (10, 18)])
+@pytest.mark.parametrize("rank,hidden", [(6, 12), (10, 18), (8, 16)])
 def test_decode_tile_compiles(one_chip, rank, hidden, batch):
     """The fused decode tile at the paper's SMALL and MEDIUM widths, over
-    the pems_sf folding, through the wrapper's batch padding."""
+    the pems_sf folding, and at granite's over its d' 17 leaf, through
+    the wrapper's batch padding."""
     from repro.kernels import ops
 
-    spec, operands = _fused_operands(rank, hidden)
+    fold = GRANITE_EMBED if (rank, hidden) == (8, 16) else ((963, 144, 440), None)
+    spec, operands = _fused_operands(rank, hidden, *fold)
     idx = jax.ShapeDtypeStruct((batch, spec.d_prime), jnp.int32, sharding=one_chip)
     compiled = _compile(
         lambda i, *w: ops.nttd_decode_tile(i, *w, impl="pallas"),
         idx, *_shapes(operands, one_chip),
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _has_decode_tile(compiled)
+
+
+def test_restore_slab_compiles(one_chip, monkeypatch):
+    """One slab program of the device restore at ``SLAB_ENTRIES``, over
+    granite's d' 17 leaf: the tile inside it keeps its name.  The slab
+    asks ``jax.default_backend()`` for its decode path, which sees this
+    host's CPU, so the test answers for the described chip."""
+    from repro.core import nttd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape, d_prime = GRANITE_EMBED
+    spec, operands = _fused_operands(8, 16, shape, d_prime)
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    compiled = nttd.restore_slab.lower(
+        sds((spec.n_entries,), jnp.float32), sds((), jnp.int32),
+        _shapes(operands, one_chip), tuple(sds((m,), jnp.int32) for m in shape),
+        sds((), jnp.float32), sds((), jnp.float32),
+        shape=shape, factors=tuple(tuple(int(f) for f in row) for row in spec.factors),
+        slab=nttd.SLAB_ENTRIES,
+    ).compile()
+    assert _has_decode_tile(compiled)
 
 
 @pytest.mark.parametrize("batch", [1, 300, 4096])
