@@ -16,14 +16,13 @@ services — the multi-process cell CI gates on: every batch must still be
 bit-identical to the single resident instance, across the process
 boundary and across a live rebalance that terminates one worker.
 
-The fused-path cells serve an NTTD payload decoded via
-``kernel_impl="fused"`` (one compiled program per decode tile instead of
-the multi-launch op chain; ``REPRO_DECODE_IMPL`` opts the whole fleet in,
-spawned workers included) and measure the COLD pass — the only pass where
-tile decode compute exists to overlap — with chunk/tile prefetching off
-and on.  Answers must be bit-identical across prefetch settings and
-against the single resident service; the prefetch-on cell lands in
-``runs`` for ``check_bench`` to gate.
+The fused-path cells serve an NTTD payload, whose served decodes run
+one compiled program per decode tile (the Pallas kernel on a TPU, the
+jitted oracle elsewhere), and measure the COLD pass — the only pass
+where tile decode compute exists to overlap — with chunk/tile
+prefetching off and on.  Answers must be bit-identical across prefetch
+settings and against the single resident service; the prefetch-on cell
+lands in ``runs`` for ``check_bench`` to gate.
 
 Results land in ``results/BENCH_fleet.json`` (in-process) or
 ``results/BENCH_fleet_procs.json`` (``--procs``) so CI tracks both fleet
@@ -134,64 +133,60 @@ def _fused_prefetch_cells(
     cache hits), so it is the only pass where I/O overlap can show.  Both
     cells must be bit-identical to the single resident reference."""
     path = _ensure_nttd_payload()
-    os.environ["REPRO_DECODE_IMPL"] = "fused"  # spawned workers inherit
-    try:
-        probe = CodecService()
-        probe.load_stream("nttd", path)
-        shape = probe.shape_of("nttd")
-        n_entries = int(np.prod(shape))
-        tile_entries = max(n_entries // 64, 64)
-        n_batches, batch = (8, 512) if smoke else (24, 2048)
-        batches = _batches(shape, n_batches, batch, seed=7)
-        single = CodecService()
-        single.load_stream("nttd", path, tile_entries=tile_entries)
-        reference = [single.decode_at("nttd", idx) for idx in batches]
+    probe = CodecService()
+    probe.load_stream("nttd", path)
+    shape = probe.shape_of("nttd")
+    n_entries = int(np.prod(shape))
+    tile_entries = max(n_entries // 64, 64)
+    n_batches, batch = (8, 512) if smoke else (24, 2048)
+    batches = _batches(shape, n_batches, batch, seed=7)
+    single = CodecService()
+    single.load_stream("nttd", path, tile_entries=tile_entries)
+    reference = [single.decode_at("nttd", idx) for idx in batches]
 
-        # throwaway cold pass: absorb one-time process state (jit compiles,
-        # page cache, allocator growth) so the off/on cells — which run in
-        # a fixed order — compare prefetching, not process warm-up
-        warm = _make_fleet(n, procs)
-        warm.load_stream("nttd", path, tile_entries=tile_entries)
-        for idx in batches:
-            warm.decode_at("nttd", idx)
-        warm.close()
+    # throwaway cold pass: absorb one-time process state (jit compiles,
+    # page cache, allocator growth) so the off/on cells — which run in
+    # a fixed order — compare prefetching, not process warm-up
+    warm = _make_fleet(n, procs)
+    warm.load_stream("nttd", path, tile_entries=tile_entries)
+    for idx in batches:
+        warm.decode_at("nttd", idx)
+    warm.close()
 
-        cells = []
-        outputs: dict[bool, list[np.ndarray]] = {}
-        for pf in (False, True):
-            fleet = _make_fleet(n, procs, prefetch=pf)
-            fleet.load_stream("nttd", path, tile_entries=tile_entries)
-            outs = []
-            t0 = time.perf_counter()
-            for idx in batches:  # COLD: every tile decodes exactly once
-                outs.append(fleet.decode_at("nttd", idx))
-            dt = time.perf_counter() - t0
-            fleet.close()
-            outputs[pf] = outs
-            for out, ref in zip(outs, reference):
-                assert np.array_equal(out, ref), "fused fleet drifted from single"
-            eps = n_batches * batch / dt
-            cells.append({
-                "instances": n,
-                "payload": "nttd",
-                "decode_impl": "fused",
-                "pass": "cold",
-                "prefetch": pf,
-                "entries_per_sec": round(eps, 1),
-                "p99_ms": None,
-            })
-            emit(
-                f"fleet_fused_cold_prefetch{'_on' if pf else '_off'}",
-                dt * 1e6 / n_batches,
-                f"entries_per_sec={eps:.0f};bit_identical=True",
-            )
-        for a, b in zip(outputs[False], outputs[True]):
-            assert np.array_equal(a, b), "prefetch changed answers"
-        off, on = (c["entries_per_sec"] for c in cells)
-        emit("fleet_fused_prefetch_speedup", 0.0, f"{on / off:.3f}x")
-        return cells
-    finally:
-        os.environ.pop("REPRO_DECODE_IMPL", None)
+    cells = []
+    outputs: dict[bool, list[np.ndarray]] = {}
+    for pf in (False, True):
+        fleet = _make_fleet(n, procs, prefetch=pf)
+        fleet.load_stream("nttd", path, tile_entries=tile_entries)
+        outs = []
+        t0 = time.perf_counter()
+        for idx in batches:  # COLD: every tile decodes exactly once
+            outs.append(fleet.decode_at("nttd", idx))
+        dt = time.perf_counter() - t0
+        fleet.close()
+        outputs[pf] = outs
+        for out, ref in zip(outs, reference):
+            assert np.array_equal(out, ref), "fused fleet drifted from single"
+        eps = n_batches * batch / dt
+        cells.append({
+            "instances": n,
+            "payload": "nttd",
+            "decode_impl": "fused",
+            "pass": "cold",
+            "prefetch": pf,
+            "entries_per_sec": round(eps, 1),
+            "p99_ms": None,
+        })
+        emit(
+            f"fleet_fused_cold_prefetch{'_on' if pf else '_off'}",
+            dt * 1e6 / n_batches,
+            f"entries_per_sec={eps:.0f};bit_identical=True",
+        )
+    for a, b in zip(outputs[False], outputs[True]):
+        assert np.array_equal(a, b), "prefetch changed answers"
+    off, on = (c["entries_per_sec"] for c in cells)
+    emit("fleet_fused_prefetch_speedup", 0.0, f"{on / off:.3f}x")
+    return cells
 
 
 def run(smoke: bool = False, procs: int | None = None) -> None:

@@ -2,12 +2,12 @@
 TPU target and is validated, not timed, in this container).
 
 The fused-decode section is the roofline record for ROADMAP item 3: it
-times the serving hot path (``nttd.apply_at_positions``) as dispatched by
-``CompressedTensor.decode`` — EAGER, multi-launch, one dispatch per op —
-against ``kernel_impl="fused"`` (one program: the Pallas kernel on TPU,
-the jitted oracle on CPU), validates interpret-mode bit-parity against
-the oracle, and writes ``results/BENCH_kernels.json`` for ``check_bench``
-to gate.
+times the jnp chain (``nttd.apply_at_positions``) EAGER, multi-launch,
+one dispatch per op, against the served decode tile
+(``ops.nttd_decode_tile``, one program: the Pallas kernel on TPU, the
+jitted oracle on CPU), validates interpret-mode bit-parity against the
+oracle, and writes ``results/BENCH_kernels.json`` for ``check_bench`` to
+gate.
 """
 from __future__ import annotations
 
@@ -23,7 +23,7 @@ from benchmarks.common import RESULTS_DIR, emit
 from repro.compile_cache import enable_compile_cache
 from repro.core import nttd
 from repro.core.folding import make_folding_spec
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 def _time(fn, *args, reps=10):
@@ -51,9 +51,8 @@ def decode_tile_roofline(smoke: bool = False) -> dict:
     """Fused vs multi-launch NTTD decode on one serving tile workload."""
     shape = (48, 40, 32)
     spec = make_folding_spec(shape)
-    cfg_ref = nttd.NTTDConfig(rank=8, hidden=16, kernel_impl="ref")
-    cfg_fused = nttd.NTTDConfig(rank=8, hidden=16, kernel_impl="fused")
-    params = nttd.init_params(jax.random.PRNGKey(0), spec, cfg_ref)
+    cfg = nttd.NTTDConfig(rank=8, hidden=16)
+    params = nttd.init_params(jax.random.PRNGKey(0), spec, cfg)
     bsz = 1024 if smoke else 4096
     rng = np.random.default_rng(0)
     pos = jnp.asarray(
@@ -64,26 +63,24 @@ def decode_tile_roofline(smoke: bool = False) -> dict:
     # so parity is BITWISE (the gate tests also sweep this; the bench
     # asserts it on the exact workload being timed)
     folded = spec.fold_indices(pos)
-    flat = nttd.fused_decode_inputs(params, spec, cfg_fused)
+    flat = nttd.fused_decode_inputs(params, spec, cfg)
     got_i = np.asarray(
-        ops.nttd_decode_tile(folded, *flat, impl="pallas_interpret", tile_b=256)
+        ops.nttd_decode_tile(folded, *flat, interpret=True, tile_b=256)
     )
-    got_f = np.asarray(ops.nttd_decode_tile(folded, *flat, impl="fused"))
+    got_f = np.asarray(ops.nttd_decode_tile(folded, *flat))
     assert np.array_equal(got_i, got_f), "interpret kernel drifted from oracle"
 
-    # multi-launch: the eager serving path (CompressedTensor.decode runs
-    # apply_at_positions un-jitted — one dispatch per op in the chain)
-    multi = lambda p: nttd.apply_at_positions(params, p, spec, cfg_ref)  # noqa: E731
+    # multi-launch: the jnp chain un-jitted — one dispatch per op
+    multi = lambda p: nttd.apply_at_positions(params, p, spec, cfg)  # noqa: E731
     dt_multi = _time_eager(multi, pos, reps=3 if smoke else 10)
 
-    # fused: one XLA program end-to-end (jitted via make_predict)
-    predict = nttd.make_predict(spec, cfg_fused)
-    fused = lambda p: predict(params, p)  # noqa: E731
+    # fused: the fold and the decode tile as one XLA program
+    fused = jax.jit(lambda p: ops.nttd_decode_tile(spec.fold_indices(p), *flat))
     dt_fused = _time(fused, pos, reps=10 if smoke else 50)
 
     # roofline accounting: weight bytes stream once per tile, flops are
     # dominated by the per-entry LSTM gate matmuls
-    t_steps, hid, rank = spec.d_prime, cfg_ref.hidden, cfg_ref.rank
+    t_steps, hid, rank = spec.d_prime, cfg.hidden, cfg.rank
     flops_per_entry = t_steps * (2 * 2 * hid * 4 * hid) + 2 * hid * (
         2 * rank + (t_steps - 2) * rank * rank
     )
@@ -113,7 +110,7 @@ def run(smoke: bool = False) -> None:
     f = jnp.asarray(rng.normal(size=(b, r)), jnp.float32)
     m = jnp.asarray(rng.normal(size=(b, k, r, r)) * 0.2, jnp.float32)
     last = jnp.asarray(rng.normal(size=(b, r)), jnp.float32)
-    fn = jax.jit(lambda a, bb, c: ops.tt_contract(a, bb, c, impl="ref"))
+    fn = jax.jit(ref.tt_contract)
     dt = _time(fn, f, m, last)
     emit("kernel_tt_contract_ref", dt * 1e6, f"B={b};K={k};R={r};{b/dt/1e6:.1f}M entries/s")
 
@@ -122,7 +119,7 @@ def run(smoke: bool = False) -> None:
     wi = jnp.asarray(rng.normal(size=(h, 4 * h)) * 0.3, jnp.float32)
     wh = jnp.asarray(rng.normal(size=(h, 4 * h)) * 0.3, jnp.float32)
     bb = jnp.zeros((4 * h,), jnp.float32)
-    fn = jax.jit(lambda *a: ops.lstm_scan(*a, impl="ref"))
+    fn = jax.jit(ref.lstm_scan)
     dt = _time(fn, x, wi, wh, bb)
     emit("kernel_lstm_ref", dt * 1e6, f"B={b};T={t};H={h};{b/dt/1e6:.1f}M seq/s")
 

@@ -127,7 +127,6 @@ def _canary_cells(path, batches, tile_entries, repeats):
 
 def run(smoke: bool = False, procs: int | None = None) -> None:
     path = _ensure_nttd_payload()
-    os.environ["REPRO_DECODE_IMPL"] = "fused"  # spawned workers inherit
     n = procs if procs is not None else 3
     n_batches, batch, repeats = (16, 2048, 15) if smoke else (24, 4096, 21)
     rec = obs.get_recorder()
@@ -261,7 +260,6 @@ def run(smoke: bool = False, procs: int | None = None) -> None:
             f"canary overhead {canary_pct:.2f}% exceeds the 10% budget"
         )
     finally:
-        os.environ.pop("REPRO_DECODE_IMPL", None)
         os.environ.pop("REPRO_TRACE", None)
         obs.disable_tracing()
         obs.get_recorder().clear()

@@ -142,10 +142,12 @@ def reference(ct, indices: np.ndarray) -> np.ndarray:
     return _unnormalize(ct, vals)
 
 
-def decode_direct(ct, indices: np.ndarray, impl: str) -> np.ndarray:
-    """The fused decode tile called straight, at the given ``impl``."""
+def decode_direct(ct, indices: np.ndarray, interpret: bool) -> np.ndarray:
+    """The fused decode tile called straight (under the Pallas interpreter
+    with ``interpret``)."""
     operands = nttd.fused_decode_inputs(ct.params, ct.spec, ct.cfg)
-    return _unnormalize(ct, ops.nttd_decode_tile(_folded(ct, indices), *operands, impl=impl))
+    return _unnormalize(
+        ct, ops.nttd_decode_tile(_folded(ct, indices), *operands, interpret=interpret))
 
 
 def serve(path: str, batches: list[np.ndarray]) -> tuple[dict, dict]:
@@ -178,16 +180,16 @@ def serve(path: str, batches: list[np.ndarray]) -> tuple[dict, dict]:
     }
 
 
-def decode_rates(ct, indices: np.ndarray, impl: str) -> dict[str, float]:
-    """Warm entries/s of the decode tile at ``impl`` and of the XLA oracle
-    at full precision, on device-resident operands (host clock, median of
-    3 calls after one warm-up)."""
+def decode_rates(ct, indices: np.ndarray, interpret: bool) -> dict[str, float]:
+    """Warm entries/s of the decode tile and of the XLA oracle at full
+    precision, on device-resident operands (host clock, median of 3 calls
+    after one warm-up)."""
     folded = _folded(ct, indices)
     operands = nttd.fused_decode_inputs(ct.params, ct.spec, ct.cfg)
     with jax.default_matmul_precision("highest"):
         oracle = jax.jit(ref.nttd_decode_tile)
         fns = {
-            impl: lambda: ops.nttd_decode_tile(folded, *operands, impl=impl),
+            "tile": lambda: ops.nttd_decode_tile(folded, *operands, interpret=interpret),
             "xla_oracle": lambda: oracle(folded, *operands),
         }
         rates = {}
@@ -203,11 +205,12 @@ def decode_rates(ct, indices: np.ndarray, impl: str) -> dict[str, float]:
 
 
 def run(
-    seed: int = 0, *, mini: bool = False, impl: str = "pallas", out_dir: Path = OUT_DIR
+    seed: int = 0, *, mini: bool = False, interpret: bool = False, out_dir: Path = OUT_DIR
 ) -> dict:
     """Every phase once; raises on the first failed check and returns the
-    report.  ``mini`` takes the dataset's CPU-sized shape, and ``impl`` is
-    what the decode tile is called with directly."""
+    report.  ``mini`` takes the dataset's CPU-sized shape, and
+    ``interpret`` runs the directly called decode tile under the Pallas
+    interpreter."""
     rep: dict = {}
     t0 = time.perf_counter()
     x = synthetic_tensors.load(DATASET, mini=mini, seed=seed)
@@ -253,7 +256,7 @@ def run(
 
     ct = enc.ct
     refs = [reference(ct, b) for b in batches]
-    answers[f"direct.{impl}"] = [decode_direct(ct, b, impl) for b in batches]
+    answers["direct.tile"] = [decode_direct(ct, b, interpret) for b in batches]
     errors = {}
     for surface, outs in answers.items():
         _check(all(o is not None for o in outs), f"{surface}: a ticket went unanswered")
@@ -261,7 +264,7 @@ def run(
             float(np.max(np.abs(np.asarray(o, np.float64) - r))) / ct.norm_std
             for o, r in zip(outs, refs)
         )
-    rep["decode_entries_per_s"] = decode_rates(ct, batches[-1], impl)
+    rep["decode_entries_per_s"] = decode_rates(ct, batches[-1], interpret)
     rep["max_err_over_std"] = errors
     rep["tolerance"] = TOL
     worst = max(errors, key=errors.get)

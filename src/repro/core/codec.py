@@ -30,6 +30,7 @@ from repro import obs
 from repro.codecs.indexing import flat_to_multi
 from repro.core import nttd, reorder
 from repro.core.folding import FoldingSpec, make_folding_spec
+from repro.kernels import ops
 from repro.optim import optimizers
 
 
@@ -48,7 +49,6 @@ class CodecConfig:
     reorder_samples: int = 4096    # sampled entries per slice for delta-loss
     normalize: bool = True         # standardize input (2 floats in payload)
     seed: int = 0
-    kernel_impl: str = "ref"
     entries_per_epoch: int | None = None  # cap for very large tensors
     tol: float = 1e-4              # fitness convergence tolerance
     patience: int = 3
@@ -80,34 +80,38 @@ class CompressedTensor:
     def decode(self, indices: np.ndarray) -> np.ndarray:
         """Approximate entries at ORIGINAL indices [B, d] -> [B].
 
-        Spans: ``payload.orig_to_pos`` (the host gather), ``nttd.fold``
-        (upload and the eager fold), ``nttd.operands`` (see
-        ``_decode_operands``), then ``nttd.apply``'s own, and
+        The fused decode tile (``ops.nttd_decode_tile``) answers, and the
+        training chain (``nttd.apply``) where the tile cannot (see
+        ``_decode_operands``).  Spans: ``payload.orig_to_pos`` (the host
+        gather), ``nttd.fold`` (upload and the eager fold),
+        ``nttd.operands``, then the tile's ``kernel_decode``, and
         ``payload.device_wait`` (the host blocked on the answer)."""
         with obs.span("payload.decode", entries=len(indices)):
             with obs.span("payload.orig_to_pos"):
                 pos = self._orig_to_pos(indices)
             with obs.span("nttd.fold"):
                 folded = self.spec.fold_indices(jnp.asarray(pos, jnp.int32))
-            vals = nttd.apply(
-                self.params, folded, self.spec, self.cfg,
-                operands=self._decode_operands(),
-            )
+            operands = self._decode_operands()
+            if operands is None:
+                vals = nttd.apply(self.params, folded, self.spec, self.cfg)
+            else:
+                vals = ops.nttd_decode_tile(folded, *operands)
             with obs.span("payload.device_wait"):
                 vals = np.asarray(vals)
             return vals * self.norm_std + self.norm_mean
 
     def _decode_operands(self) -> tuple[jax.Array, ...] | None:
         """The fused decode's operands (``nttd.fused_decode_inputs``), on
-        the device and stacked once per parameter set; ``None`` where
-        ``nttd.apply`` takes another branch.
+        the device and stacked once per parameter set; ``None`` for a
+        folding of fewer than two steps, which the tile refuses (only an
+        explicit ``d_prime=1`` over short modes makes one).
 
         Kept beside the ``params`` object they were built from, outside
         the dataclass fields, and rebuilt when ``self.params`` is another
         object (an ``id`` may be reused once the old tree is collected).
         The ``nttd.operands`` span wraps the lookup; its ``built`` is 1
         when this call stacked them and 0 when it reused them."""
-        if not nttd.uses_fused_decode(self.spec, self.cfg):
+        if self.spec.d_prime < 2:
             return None
         params = self.params  # one read: the pair stored must match
         built_from, operands = getattr(self, "_operands", (None, None))
@@ -178,21 +182,6 @@ class CompressionLog:
     epochs_run: int = 0
 
 
-def _make_train_step(spec: FoldingSpec, cfg: nttd.NTTDConfig, opt):
-    def loss_fn(params, positions, values):
-        preds = nttd.apply_at_positions(params, positions, spec, cfg)
-        return jnp.sum(jnp.square(preds - values))
-
-    @jax.jit
-    def step(params, opt_state, positions, values):
-        loss, grads = jax.value_and_grad(loss_fn)(params, positions, values)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optimizers.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
-
-
 def _make_train_epoch(spec: FoldingSpec, cfg: nttd.NTTDConfig, opt):
     """Whole-epoch jitted step: lax.scan over minibatches.
 
@@ -234,9 +223,7 @@ def compress(
     x = np.asarray(x, dtype=np.float32)
     d = x.ndim
     spec = make_folding_spec(x.shape, config.d_prime)
-    cfg = nttd.NTTDConfig(
-        rank=config.rank, hidden=config.hidden, kernel_impl=config.kernel_impl
-    )
+    cfg = nttd.NTTDConfig(rank=config.rank, hidden=config.hidden)
 
     mean, std = 0.0, 1.0
     if config.normalize:

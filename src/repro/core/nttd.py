@@ -20,9 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
 from repro.core.folding import FoldingSpec, spec_from_factors
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 Params = dict[str, Any]
 
@@ -32,7 +31,6 @@ class NTTDConfig:
     rank: int = 8            # R, TT rank
     hidden: int = 16         # h, LSTM hidden == embedding dim
     dtype: Any = jnp.float32
-    kernel_impl: str = "ref"  # see kernels.ops
 
 
 def _mode_table_names(folded_shape: tuple[int, ...]) -> list[str]:
@@ -110,35 +108,19 @@ def fused_decode_inputs(
     )
 
 
-def uses_fused_decode(spec: FoldingSpec, cfg: NTTDConfig) -> bool:
-    """Whether ``apply`` decodes through the fused kernel, the one branch
-    that takes ``fused_decode_inputs``."""
-    return cfg.kernel_impl == "fused" and spec.d_prime >= 2
-
-
 def apply(
     params: Params,
     folded_idx: jax.Array,  # [B, d'] int32
     spec: FoldingSpec,
     cfg: NTTDConfig,
-    operands: tuple[jax.Array, ...] | None = None,
 ) -> jax.Array:
     """Approximate entries at the given folded indices.  Returns [B].
 
-    ``operands``, on the fused branch only, is ``fused_decode_inputs`` of
-    ``params`` built beforehand; ``None`` stacks them here (inside a
-    caller's ``jit``, as part of its program)."""
+    The differentiable chain that training, evaluation and reordering
+    run; a served decode runs the fused tile instead
+    (``ops.nttd_decode_tile``), which agrees with it to float rounding."""
     d_prime = spec.d_prime
     r = cfg.rank
-    if uses_fused_decode(spec, cfg):
-        # single-program decode: whole chain in one kernel / one XLA program
-        # (Pallas on TPU, jitted oracle on CPU — see kernels.ops)
-        if operands is None:
-            with obs.span("nttd.operands", built=1):
-                operands = fused_decode_inputs(params, spec, cfg)
-        return ops.nttd_decode_tile(
-            folded_idx.astype(jnp.int32), *operands, impl="fused"
-        )
     # --- embedding lookup (shared tables by mode length) -------------------
     embeds = [
         params[f"embed_{m}"][folded_idx[:, j]] for j, m in enumerate(spec.folded_shape)
@@ -146,7 +128,7 @@ def apply(
     x = jnp.stack(embeds, axis=1)  # [B, d', h]
     # --- LSTM encoder -------------------------------------------------------
     lstm = params["lstm"]
-    hs = ops.lstm_scan(x, lstm["wi"], lstm["wh"], lstm["b"], impl=cfg.kernel_impl)
+    hs = ref.lstm_scan(x, lstm["wi"], lstm["wh"], lstm["b"])
     # --- TT-core heads --------------------------------------------------------
     first = hs[:, 0] @ params["head_first"]["w"] + params["head_first"]["b"]  # [B, R]
     last = hs[:, -1] @ params["head_last"]["w"] + params["head_last"]["b"]    # [B, R]
@@ -157,7 +139,7 @@ def apply(
     else:
         mids = jnp.zeros((folded_idx.shape[0], 0, r, r), cfg.dtype)
     # --- chain contraction ----------------------------------------------------
-    return ops.tt_contract(first, mids, last, impl=cfg.kernel_impl)
+    return ref.tt_contract(first, mids, last)
 
 
 def apply_at_positions(
@@ -202,7 +184,7 @@ def _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab):
         pos[k] = jnp.take(inv_pi[k], rem % shape[k])
         rem = rem // shape[k]
     folded = spec_from_factors(shape, factors).fold_modes(pos)
-    vals = ops.nttd_decode_tile(folded, *operands, impl="fused")
+    vals = ops.nttd_decode_tile(folded, *operands)
     return vals.astype(jnp.float32) * std + mean
 
 

@@ -20,9 +20,9 @@ for new code.
 from __future__ import annotations
 
 import io
-import os
 import struct
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -111,19 +111,8 @@ def save_bytes(ct: codec_mod.CompressedTensor, dtype=np.float32) -> bytes:
     return out.getvalue()
 
 
-def load_bytes(
-    data: bytes, kernel_impl: str | None = None
-) -> codec_mod.CompressedTensor:
-    """Rebuild a CompressedTensor from its v2 body.
-
-    ``kernel_impl`` picks the decode backend of the rebuilt payload (the
-    wire format carries no impl — it is an execution choice, not data).
-    Default is "fused" on a TPU, so served decodes run the Pallas tile,
-    and "ref" elsewhere, which keeps CPU decodes of stored payloads
-    bit-stable.  ``REPRO_DECODE_IMPL`` overrides it process-wide.
-    """
-    import jax
-
+def load_bytes(data: bytes) -> codec_mod.CompressedTensor:
+    """Rebuild a CompressedTensor from its v2 body."""
     buf = io.BytesIO(data)
     if buf.read(4) != MAGIC:
         raise ValueError("not a TensorCodec payload")
@@ -136,13 +125,7 @@ def load_bytes(
     factors = np.frombuffer(buf.read(d * d_prime), dtype=np.uint8).reshape(d, d_prime)
     # the stored factors, not today's chooser, define the payload's folding
     spec = spec_from_factors(shape, factors)
-    cfg = nttd.NTTDConfig(
-        rank=rank,
-        hidden=hidden,
-        kernel_impl=kernel_impl
-        or os.environ.get("REPRO_DECODE_IMPL")
-        or ("fused" if jax.default_backend() == "tpu" else "ref"),
-    )
+    cfg = nttd.NTTDConfig(rank=rank, hidden=hidden)
     dtype = _DTYPES[code]
     # rebuild an abstract params tree to know the shapes, then fill
     template = jax.eval_shape(

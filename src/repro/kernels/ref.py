@@ -1,9 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp functions of the kernels package.
 
-These are the semantic ground truth: each Pallas kernel is validated
-against the function of the same name here (interpret=True on CPU,
-compiled on TPU).  They are also the execution path used on non-TPU
-backends (tests, benches, the CPU dry-run).
+``lstm_scan`` and ``tt_contract`` are the differentiable NTTD chain that
+training, evaluation and reordering run (``nttd.apply``).  The others
+are the oracles of the Pallas kernels, their semantic ground truth: each
+kernel is validated against the function of the same name here
+(interpret=True on CPU, compiled on TPU), and the oracle is the
+execution path on non-TPU backends.
 """
 from __future__ import annotations
 
@@ -35,15 +37,6 @@ def tt_contract(first: jax.Array, mid: jax.Array, last: jax.Array) -> jax.Array:
     return jnp.sum(v * last, axis=-1)
 
 
-def tt_contract_unrolled(first: jax.Array, mid: jax.Array, last: jax.Array) -> jax.Array:
-    """Chain product with the K loop unrolled (K is tiny for NTTD); XLA
-    fuses the whole chain into one kernel instead of K loop iterations."""
-    v = first
-    for k in range(mid.shape[1]):
-        v = jnp.einsum("br,brs->bs", v, mid[:, k])
-    return jnp.sum(v * last, axis=-1)
-
-
 # ----------------------------------------------------------------------------
 # Fused LSTM scan (NTTD, Alg. 2 line 3)
 # ----------------------------------------------------------------------------
@@ -59,9 +52,8 @@ def lstm_scan(
     returns hidden states [B, T, H] in ``x.dtype``
 
     Gate layout along the 4H axis: (i, f, g, o).  Carries and gate math
-    run in f32 regardless of ``x.dtype`` — the Pallas kernel computes in
-    f32 and casts back, so the oracle must too or bf16 parity tests
-    compare unlike against unlike.
+    run in f32 regardless of ``x.dtype`` and cast back, as the decode
+    tile does, so bf16 comparisons with the tile compare like with like.
     """
     bsz, _, hid = x.shape
     xf = x.astype(jnp.float32)
@@ -83,29 +75,6 @@ def lstm_scan(
     )
     _, hs = jax.lax.scan(step, init, jnp.moveaxis(xf, 1, 0))
     return jnp.moveaxis(hs, 0, 1).astype(x.dtype)
-
-
-def lstm_unrolled(
-    x: jax.Array, wi: jax.Array, wh: jax.Array, b: jax.Array
-) -> jax.Array:
-    """Same semantics as lstm_scan with the time loop unrolled in Python
-    (T is tiny for NTTD); XLA fuses across steps.  f32 internally, like
-    lstm_scan and the Pallas kernel."""
-    bsz, t_steps, hid = x.shape
-    xf = x.astype(jnp.float32)
-    wif = wi.astype(jnp.float32)
-    whf = wh.astype(jnp.float32)
-    bf = b.astype(jnp.float32)
-    h = jnp.zeros((bsz, hid), dtype=jnp.float32)
-    c = jnp.zeros((bsz, hid), dtype=jnp.float32)
-    outs = []
-    for t in range(t_steps):
-        gates = xf[:, t] @ wif + h @ whf + bf
-        i, f, g, o = jnp.split(gates, 4, axis=-1)
-        c = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-        h = jax.nn.sigmoid(o) * jnp.tanh(c)
-        outs.append(h)
-    return jnp.stack(outs, axis=1).astype(x.dtype)
 
 
 # ----------------------------------------------------------------------------

@@ -3,7 +3,7 @@ training step, data-parallel over sampled tensor entries on the
 production mesh.
 
     PYTHONPATH=src python -m repro.launch.dryrun_codec \
-        [--mesh single|multi] [--impl ref|ref_unrolled] \
+        [--mesh single|multi] \
         [--batch 65536] [--steps 8] [--rank 8] [--hidden 16]
 
 Reports the same three-term roofline as the LM cells.  This is the
@@ -36,11 +36,11 @@ from repro.optim import optimizers
 DEFAULT_SHAPE = (16384, 4096, 1024)
 
 
-def run(mesh_name: str, impl: str, batch: int, steps: int, rank: int,
+def run(mesh_name: str, batch: int, steps: int, rank: int,
         hidden: int, shape=DEFAULT_SHAPE, verbose: bool = True) -> dict:
     mesh = mesh_lib.make_production_mesh(multi_pod=mesh_name == "multi")
     spec = make_folding_spec(shape)
-    cfg = nttd.NTTDConfig(rank=rank, hidden=hidden, kernel_impl=impl)
+    cfg = nttd.NTTDConfig(rank=rank, hidden=hidden)
     opt = optimizers.adam(1e-2)
     epoch_fn = codec_lib._make_train_epoch(spec, cfg, opt)
 
@@ -94,7 +94,7 @@ def run(mesh_name: str, impl: str, batch: int, steps: int, rank: int,
     )
     res = {
         "arch": "tensorcodec-codec",
-        "shape": f"entries{batch}x{steps}_impl-{impl}",
+        "shape": f"entries{batch}x{steps}",
         "mesh": mesh_name,
         "rules": "dp",
         "status": "ok",
@@ -123,7 +123,7 @@ def run(mesh_name: str, impl: str, batch: int, steps: int, rank: int,
         ),
     }
     if verbose:
-        print(f"[codec x {mesh_name} x impl={impl} x batch={batch}]")
+        print(f"[codec x {mesh_name} x batch={batch}]")
         print(f"  memory: args={mem.argument_size_in_bytes/1e6:.1f}MB "
               f"temp={mem.temp_size_in_bytes/1e6:.1f}MB")
         print(f"  flops/dev={flops:.3e} bytes/dev={bytes_:.3e} "
@@ -136,15 +136,14 @@ def run(mesh_name: str, impl: str, batch: int, steps: int, rank: int,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="single", choices=["single", "multi"])
-    ap.add_argument("--impl", default="ref", choices=["ref", "ref_unrolled"])
     ap.add_argument("--batch", type=int, default=1 << 20)
     ap.add_argument("--steps", type=int, default=4)
     ap.add_argument("--rank", type=int, default=8)
     ap.add_argument("--hidden", type=int, default=16)
     args = ap.parse_args()
     enable_compile_cache()
-    res = run(args.mesh, args.impl, args.batch, args.steps, args.rank, args.hidden)
-    path = dryrun.cell_path("tensorcodec-codec", f"b{args.batch}-{args.impl}",
+    res = run(args.mesh, args.batch, args.steps, args.rank, args.hidden)
+    path = dryrun.cell_path("tensorcodec-codec", f"b{args.batch}",
                             args.mesh, "dp")
     with open(path, "w") as f:
         json.dump(res, f, indent=2)

@@ -12,10 +12,12 @@ pipeline — ``FleetFrontend.decode_at`` → ``Transport`` wire →
 trace, and exports Chrome trace-event JSON that Perfetto loads directly.
 ``nttd.operands`` wraps the lookup of the fused decode's operands, which
 a payload stacks once per parameter set: its ``built`` is 1 when that
-call stacked them and 0 when it reused them.  ``kernel_decode`` times the
-enqueue of an asynchronous call (its ``tile`` is the kernel's lanes a
-grid step); the kernel's device time, the batch pad and transpose with
-it, is ``jit_decode_tile`` in a profiler trace.
+call stacked them and 0 when it reused them.  ``kernel_decode``, on every
+served decode, times the enqueue of an asynchronous call; its ``impl``
+names the path taken (``pallas`` on a TPU, ``pallas_interpret``,
+``oracle`` elsewhere), its ``tile`` the kernel's lanes a grid step.  The
+kernel's device time, the batch pad and transpose with it, is
+``jit_decode_tile`` in a profiler trace.
 The streaming fit records ``fit.update`` with ``fit.sample``,
 ``fit.dispatch`` and ``fit.reservoir`` inside it.  Every live span is
 also a ``jax.profiler.TraceAnnotation``, so a profiler trace holds the

@@ -64,11 +64,12 @@ class NTTDStreamFitter(StreamFitter):
         kernel_impl: str = "ref",
         normalize: bool = True,
     ):
+        # one value left, the jnp chain; the next benchmark change drops it
+        if kernel_impl != "ref":
+            raise ValueError(f"the fit runs the jnp chain ('ref'), not {kernel_impl!r}")
         self.shape = tuple(int(s) for s in shape)
         self.spec = make_folding_spec(self.shape, d_prime)
-        self.cfg = nttd.NTTDConfig(
-            rank=rank, hidden=hidden or 2 * rank, kernel_impl=kernel_impl
-        )
+        self.cfg = nttd.NTTDConfig(rank=rank, hidden=hidden or 2 * rank)
         self.seed = int(seed)
         self.batch_size = int(batch_size)
         self.steps_per_slab = int(steps_per_slab)

@@ -50,13 +50,13 @@ def test_phases_agree_at_mini_size(chip_smoke, tmp_path):
     interpret mode, and served payloads on the CPU's own decode path, all
     within the stated tolerance of the f32 oracle, with no failed ticket
     and no excluded instance."""
-    rep = chip_smoke.run(seed=3, mini=True, impl="pallas_interpret", out_dir=tmp_path)
+    rep = chip_smoke.run(seed=3, mini=True, interpret=True, out_dir=tmp_path)
     assert rep["data_shape"] == (96, 48, 56)
     assert rep["loss_last"] < rep["loss_first"]
-    assert rep["served_impls"] == []  # loaded payloads decode on "ref" here
+    assert rep["served_impls"] == ["oracle"]  # the tile's CPU path
     assert set(rep["max_err_over_std"]) == {
         f"{s}.{k}" for s in ("untiled", "tiled", "fleet") for k in ("decode_at", "submit")
-    } | {"direct.pallas_interpret"}
+    } | {"direct.tile"}
     assert max(rep["max_err_over_std"].values()) <= chip_smoke.TOL
     assert rep["failed_tickets"] == 0 and rep["excluded_instances"] == []
 

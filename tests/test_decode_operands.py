@@ -1,9 +1,10 @@
 """The fused decode's operands, stacked once per parameter set and kept
-beside the payload: answers bit-identical to ``nttd.apply`` stacking them
-itself, one ``nttd.operands`` span with ``built=1`` a parameter set and
-``built=0`` on every reuse, a rebuild when ``params`` is assigned a new
-tree, nothing built off the fused path, and a payload whose fields, bytes
-and repr do not see the cache."""
+beside the payload: answers bit-identical to the tile over freshly
+stacked operands, one ``nttd.operands`` span with ``built=1`` a
+parameter set and ``built=0`` on every reuse, a rebuild when ``params``
+is assigned a new tree, nothing built for a folding the tile cannot
+serve, and a payload whose fields, bytes and repr do not see the cache.
+And every route to a payload decodes alike."""
 import dataclasses
 
 import jax
@@ -12,32 +13,31 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import nttd, serialization
+from repro.codecs.adapters import NTTDEncoded
+from repro.core import codec, nttd, serialization
 from repro.core.codec import CompressedTensor
 from repro.core.folding import make_folding_spec
+from repro.kernels import ops
+from repro.serve.codec_service import CodecService
+from repro.stream import write_chunked
 
 SHAPE = (24, 16, 12)
 FIELDS = ["params", "pi", "spec", "cfg", "norm_mean", "norm_std"]
 
 
-def payload_bytes():
-    spec = make_folding_spec(SHAPE, None)
+def payload_bytes(shape=SHAPE, d_prime=None):
+    spec = make_folding_spec(shape, d_prime)
     cfg = nttd.NTTDConfig(rank=3, hidden=6)
     params = jax.tree.map(np.asarray, nttd.init_params(jax.random.PRNGKey(7), spec, cfg))
     rng = np.random.default_rng(3)
-    pi = [rng.permutation(n) for n in SHAPE]
+    pi = [rng.permutation(n) for n in shape]
     return serialization.save_bytes(CompressedTensor(params, pi, spec, cfg, 0.5, 2.0))
 
 
-def load(monkeypatch, impl):
-    monkeypatch.setenv("REPRO_DECODE_IMPL", impl)
-    return serialization.load_bytes(payload_bytes())
-
-
 @pytest.fixture()
-def ct(monkeypatch):
-    """A loaded payload on the fused path (the jitted oracle on the CPU)."""
-    return load(monkeypatch, "fused")
+def ct():
+    """A loaded payload (its tile runs as the jitted oracle on the CPU)."""
+    return serialization.load_bytes(payload_bytes())
 
 
 @pytest.fixture()
@@ -49,15 +49,20 @@ def recorder():
     rec.clear()
 
 
-def request(seed=0, n=500):
+def request(seed=0, n=500, shape=SHAPE):
     rng = np.random.default_rng(seed)
-    return np.stack([rng.integers(0, s, n) for s in SHAPE], axis=1)
+    return np.stack([rng.integers(0, s, n) for s in shape], axis=1)
+
+
+def folded(ct, idx):
+    return ct.spec.fold_indices(jnp.asarray(ct._orig_to_pos(idx), jnp.int32))
 
 
 def restacked(ct, idx, params=None):
-    """What ``decode`` answered before: ``nttd.apply`` without operands."""
-    folded = ct.spec.fold_indices(jnp.asarray(ct._orig_to_pos(idx), jnp.int32))
-    vals = nttd.apply(ct.params if params is None else params, folded, ct.spec, ct.cfg)
+    """The tile over operands stacked afresh from ``params``."""
+    params = ct.params if params is None else params
+    vals = ops.nttd_decode_tile(folded(ct, idx),
+                                *nttd.fused_decode_inputs(params, ct.spec, ct.cfg))
     return np.asarray(vals) * ct.norm_std + ct.norm_mean
 
 
@@ -67,7 +72,6 @@ def built_attrs(recorder):
 
 @pytest.mark.parametrize("sizes", [(1, 37, 500), (2048, 5, 2048, 300)])
 def test_answers_are_bitwise_those_of_restacking(ct, sizes):
-    assert nttd.uses_fused_decode(ct.spec, ct.cfg)
     for k, n in enumerate(sizes):
         idx = request(k, n)
         np.testing.assert_array_equal(ct.decode(idx), restacked(ct, idx))
@@ -104,10 +108,16 @@ def test_the_cache_keys_on_the_params_object_not_its_id(ct):
     assert ct._decode_operands() is not first
 
 
-def test_a_ref_payload_builds_nothing(monkeypatch, recorder):
-    ct = load(monkeypatch, "ref")
-    idx = request(2)
-    np.testing.assert_array_equal(ct.decode(idx), restacked(ct, idx))
+def test_a_ref_payload_builds_nothing(recorder):
+    """A payload folded to one step, which the tile refuses, decodes on the
+    jnp chain (``nttd.apply``) and stacks no operands."""
+    shape = (4, 5, 3)
+    ct = serialization.load_bytes(payload_bytes(shape, d_prime=1))
+    assert ct.spec.d_prime == 1
+    idx = request(2, shape=shape)
+    chain = nttd.apply(ct.params, folded(ct, idx), ct.spec, ct.cfg)
+    np.testing.assert_array_equal(ct.decode(idx),
+                                  np.asarray(chain) * ct.norm_std + ct.norm_mean)
     assert ct._decode_operands() is None
     assert "_operands" not in vars(ct)
     assert built_attrs(recorder) == []
@@ -125,3 +135,39 @@ def test_the_payload_does_not_see_the_cache(ct):
     assert "_operands" not in vars(copy)
     idx = request(4)
     np.testing.assert_array_equal(copy.decode(idx), ct.decode(idx))
+
+
+@pytest.fixture(scope="module")
+def compressed():
+    """A payload straight out of ``compress()``."""
+    x = np.random.default_rng(4).random(SHAPE).astype(np.float32)
+    ct, _ = codec.compress(x, codec.CodecConfig(rank=3, hidden=6, epochs=1,
+                                                batch_size=512, eval_batch=1024))
+    return ct
+
+
+def by_load_bytes(ct, tmp_path, idx):
+    return serialization.load_bytes(serialization.save_bytes(ct)).decode(idx)
+
+
+def by_load_stream(ct, tmp_path, idx):
+    path = str(tmp_path / "payload.tcdc")
+    write_chunked(path, NTTDEncoded(ct))
+    svc = CodecService()
+    svc.load_stream("p", path)
+    return svc.decode_at("p", idx)
+
+
+ROUTES = {"compress": lambda ct, tmp_path, idx: ct.decode(idx),
+          "load_bytes": by_load_bytes, "load_stream": by_load_stream}
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_every_route_to_a_payload_decodes_alike(compressed, route, tmp_path, recorder):
+    """The same payload from ``compress()``, from ``load_bytes`` and from an
+    untiled ``load_stream``: the tile answers on every route, bit for bit."""
+    idx = request(5, 700)
+    got = ROUTES[route](compressed, tmp_path, idx)
+    spans = [s for s in recorder.snapshot() if s.name == "kernel_decode"]
+    assert [(s.attrs["impl"], s.attrs["b"]) for s in spans] == [("oracle", len(idx))]
+    np.testing.assert_array_equal(got, restacked(compressed, idx))

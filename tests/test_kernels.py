@@ -1,56 +1,14 @@
 """Per-kernel validation: Pallas (interpret=True) vs the ref.py oracles,
-swept over shapes and dtypes (the property-sweep substitute for hypothesis,
-which is unavailable offline)."""
+and the decode tile vs the jnp training chain, swept over shapes and
+dtypes (the property-sweep substitute for hypothesis, which is
+unavailable offline)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(0)
-
-
-@pytest.mark.parametrize(
-    "b,k,r,dtype",
-    [
-        (b, k, r, dt)
-        for b, k, r in [(32, 0, 4), (64, 5, 8), (100, 10, 16), (7, 3, 8), (256, 8, 32)]
-        for dt in [jnp.float32, jnp.bfloat16]
-    ],
-    ids=lambda v: str(v).split(".")[-1] if hasattr(v, "dtype") else str(v),
-)
-def test_tt_contract_sweep(b, k, r, dtype):
-    f = jnp.asarray(RNG.normal(size=(b, r)), dtype)
-    # keep the chain product O(1) so bf16 tolerances are meaningful
-    m = jnp.asarray(RNG.normal(size=(b, k, r, r)) * (0.5 / np.sqrt(r)), dtype)
-    last = jnp.asarray(RNG.normal(size=(b, r)), dtype)
-    want = ops.tt_contract(f, m, last, impl="ref")
-    got = ops.tt_contract(f, m, last, impl="pallas_interpret", tile_b=32)
-    tol = 1e-5 if dtype == jnp.float32 else 0.15
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol
-    )
-
-
-@pytest.mark.parametrize(
-    "b,t,h,dtype",
-    [
-        (b, t, h, dt)
-        for b, t, h in [(16, 6, 8), (50, 9, 16), (33, 12, 32), (8, 3, 64)]
-        for dt in [jnp.float32, jnp.bfloat16]
-    ],
-)
-def test_lstm_scan_sweep(b, t, h, dtype):
-    x = jnp.asarray(RNG.normal(size=(b, t, h)), dtype)
-    wi = jnp.asarray(RNG.normal(size=(h, 4 * h)) * 0.3, dtype)
-    wh = jnp.asarray(RNG.normal(size=(h, 4 * h)) * 0.3, dtype)
-    bb = jnp.asarray(RNG.normal(size=(4 * h,)) * 0.1, dtype)
-    want = ops.lstm_scan(x, wi, wh, bb, impl="ref")
-    got = ops.lstm_scan(x, wi, wh, bb, impl="pallas_interpret", tile_b=16)
-    tol = 2e-5 if dtype == jnp.float32 else 0.1
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol
-    )
 
 
 @pytest.mark.parametrize(
@@ -78,8 +36,6 @@ def test_flash_attention_decode_offset():
 
 
 def test_chunked_attention_matches_ref():
-    from repro.kernels import ref
-
     b, s, h, d = 2, 4096, 2, 32
     q = jnp.asarray(RNG.normal(size=(b, s, h, d)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(b, s, h, d)), jnp.float32)
@@ -90,8 +46,6 @@ def test_chunked_attention_matches_ref():
 
 
 def test_kv_len_masking():
-    from repro.kernels import ref
-
     b, sq, skv, h, d = 3, 1, 64, 2, 16
     q = jnp.asarray(RNG.normal(size=(b, sq, h, d)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(b, skv, h, d)), jnp.float32)
@@ -135,8 +89,6 @@ def test_attention_explicit_impl_odd_kv_only():
 def test_chunked_attention_ragged_tail(causal):
     """sq=2049 = 4*512 + 1: the scan covers the aligned prefix and the
     ragged tail is finished separately (no silent full-score fallback)."""
-    from repro.kernels import ref
-
     b, s, h, d = 1, 2049, 2, 32
     q = jnp.asarray(RNG.normal(size=(b, s, h, d)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(b, s, h, d)), jnp.float32)
@@ -165,6 +117,52 @@ def _decode_tile_args(b, t, m, hid, rank, dtype, seed=1):
     )
 
 
+def _training_chain(idx, ws):
+    """``nttd.apply`` over the tile's operands: the per-step gather,
+    ``ref.lstm_scan``, the three heads, ``ref.tt_contract``."""
+    emb, wi, wh, b, wf, bf, wm, bm, wl, bl = ws
+    t, rank = idx.shape[1], bf.shape[0]
+    x = jnp.stack([emb[j][idx[:, j]] for j in range(t)], axis=1)
+    hs = ref.lstm_scan(x, wi, wh, b)
+    if t > 2:
+        mids = (hs[:, 1:-1] @ wm + bm).reshape(-1, t - 2, rank, rank)
+    else:
+        mids = jnp.zeros((idx.shape[0], 0, rank, rank), emb.dtype)
+    return ref.tt_contract(hs[:, 0] @ wf + bf, mids, hs[:, -1] @ wl + bl)
+
+
+# (B, T, H, R) with the tolerance (f32, bf16) of the tile against the chain
+CHAIN_ROWS = [
+    ((32, 2, 16, 4), (1e-5, 0.15)),
+    ((64, 7, 16, 8), (1e-5, 0.15)),
+    ((100, 12, 16, 16), (1e-5, 0.15)),
+    ((7, 5, 16, 8), (1e-5, 0.15)),
+    ((256, 10, 16, 32), (1e-5, 0.15)),
+    ((16, 6, 8, 8), (2e-5, 0.1)),
+    ((50, 9, 16, 8), (2e-5, 0.1)),
+    ((33, 12, 32, 8), (2e-5, 0.1)),
+    ((8, 3, 64, 8), (2e-5, 0.1)),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("row", CHAIN_ROWS, ids=lambda r: "x".join(map(str, r[0])))
+def test_decode_tile_matches_training_chain(row, dtype):
+    """The served decode (the tile, interpreted here) is bit-identical to
+    the jitted oracle, and both agree with the jnp chain that training
+    runs."""
+    (b, t, hid, rank), tols = row
+    idx, ws = _decode_tile_args(b, t, 10, hid, rank, dtype)
+    got = ops.nttd_decode_tile(idx, *ws, interpret=True)
+    oracle = ops.nttd_decode_tile(idx, *ws)
+    assert got.shape == (b,) and got.dtype == ws[0].dtype
+    assert np.array_equal(np.asarray(got), np.asarray(oracle))
+    tol = tols[0] if dtype == jnp.float32 else tols[1]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(_training_chain(idx, ws), np.float32),
+                               rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize(
     "rank,t,dtype",
     [
@@ -178,11 +176,9 @@ def test_decode_tile_parity_sweep(rank, t, dtype):
     """Interpret-mode Pallas is BIT-IDENTICAL to the jitted oracle (same
     compiled op order), and within eager-vs-jit ulp noise of the eager
     oracle."""
-    from repro.kernels import ref
-
     idx, ws = _decode_tile_args(32, t, 10, 16, rank, dtype)
-    got = ops.nttd_decode_tile(idx, *ws, impl="pallas_interpret", tile_b=16)
-    fused = ops.nttd_decode_tile(idx, *ws, impl="fused")
+    got = ops.nttd_decode_tile(idx, *ws, interpret=True, tile_b=16)
+    fused = ops.nttd_decode_tile(idx, *ws)
     assert got.dtype == ws[0].dtype
     assert np.array_equal(np.asarray(got), np.asarray(fused)), (
         "interpret kernel drifted from jitted oracle"
@@ -199,8 +195,8 @@ def test_decode_tile_non_multiple_batch():
     """b=33 with tile_b=16: the wrapper pads the batch to a tile multiple
     and slices the result back."""
     idx, ws = _decode_tile_args(33, 3, 7, 16, 8, jnp.float32)
-    got = ops.nttd_decode_tile(idx, *ws, impl="pallas_interpret", tile_b=16)
-    fused = ops.nttd_decode_tile(idx, *ws, impl="fused")
+    got = ops.nttd_decode_tile(idx, *ws, interpret=True, tile_b=16)
+    fused = ops.nttd_decode_tile(idx, *ws)
     assert got.shape == (33,)
     assert np.array_equal(np.asarray(got), np.asarray(fused))
 
@@ -210,30 +206,19 @@ def test_decode_tile_non_multiple_batch():
 BENCH_WIDTHS = {"pems": (18, 10, 8, 10), "nyc": (12, 6, 16, 9), "granite": (16, 8, 16, 17)}
 
 
-def _per_op_chain(idx, ws):
-    """The multi-op decode (``nttd.apply`` with ``kernel_impl="ref"``):
-    gather, ``lstm_scan``, the three heads, ``tt_contract``."""
-    emb, wi, wh, b, wf, bf, wm, bm, wl, bl = ws
-    t, rank = idx.shape[1], bf.shape[0]
-    x = jnp.stack([emb[j][idx[:, j]] for j in range(t)], axis=1)
-    hs = ops.lstm_scan(x, wi, wh, b, impl="ref")
-    mids = (hs[:, 1:-1] @ wm + bm).reshape(-1, t - 2, rank, rank)
-    return ops.tt_contract(hs[:, 0] @ wf + bf, mids, hs[:, -1] @ wl + bl, impl="ref")
-
-
 @pytest.mark.parametrize("batch", [1, 127, 300, 1000])
 @pytest.mark.parametrize("widths", list(BENCH_WIDTHS), ids=str)
 def test_decode_tile_at_bench_widths(widths, batch):
     """At each configuration's widths, batches that are not a multiple of
     128 lanes, with the tile width the wrapper picks: interpret mode is
-    bit-identical to the jitted oracle and close to the per-op chain."""
+    bit-identical to the jitted oracle and close to the training chain."""
     hid, rank, m, t = BENCH_WIDTHS[widths]
     idx, ws = _decode_tile_args(batch, t, m, hid, rank, jnp.float32)
-    got = ops.nttd_decode_tile(idx, *ws, impl="pallas_interpret")
-    fused = ops.nttd_decode_tile(idx, *ws, impl="fused")
+    got = ops.nttd_decode_tile(idx, *ws, interpret=True)
+    fused = ops.nttd_decode_tile(idx, *ws)
     assert got.shape == (batch,)
     assert np.array_equal(np.asarray(got), np.asarray(fused))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(_per_op_chain(idx, ws)),
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_training_chain(idx, ws)),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -261,16 +246,14 @@ def test_decode_tile_padded_hidden_matches_unpadded(widths):
     padded unit's c and h stay 0."""
     hid, rank, m, t = BENCH_WIDTHS[widths]
     idx, ws = _decode_tile_args(300, t, m, hid, rank, jnp.float32)
-    got = ops.nttd_decode_tile(idx, *_pad_hidden(ws, -(-hid // 8) * 8),
-                               impl="pallas_interpret")
-    assert np.array_equal(np.asarray(got),
-                          np.asarray(ops.nttd_decode_tile(idx, *ws, impl="fused")))
+    got = ops.nttd_decode_tile(idx, *_pad_hidden(ws, -(-hid // 8) * 8), interpret=True)
+    assert np.array_equal(np.asarray(got), np.asarray(ops.nttd_decode_tile(idx, *ws)))
 
 
 def test_decode_tile_empty_batch():
     idx, ws = _decode_tile_args(0, 3, 7, 16, 8, jnp.float32)
-    for impl in ("pallas_interpret", "fused", "ref", "auto"):
-        out = ops.nttd_decode_tile(idx, *ws, impl=impl)
+    for interpret in (True, False):
+        out = ops.nttd_decode_tile(idx, *ws, interpret=interpret)
         assert out.shape == (0,)
         assert out.dtype == ws[0].dtype
 
@@ -282,23 +265,23 @@ def test_decode_tile_rejects_short_chain():
 
 
 def test_fused_apply_matches_ref_apply():
-    """kernel_impl='fused' routes nttd.apply through the one-program
-    decode; values must match the per-op ref chain."""
+    """The served decode (the tile over ``fused_decode_inputs``) matches
+    ``nttd.apply``, the per-op chain that training runs."""
     import jax
 
     from repro.core import nttd
     from repro.core.folding import make_folding_spec
 
     spec = make_folding_spec((20, 18, 12))
-    cfg_ref = nttd.NTTDConfig(rank=6, hidden=12, kernel_impl="ref")
-    cfg_fused = nttd.NTTDConfig(rank=6, hidden=12, kernel_impl="fused")
-    params = nttd.init_params(jax.random.PRNGKey(3), spec, cfg_ref)
+    cfg = nttd.NTTDConfig(rank=6, hidden=12)
+    params = nttd.init_params(jax.random.PRNGKey(3), spec, cfg)
     rng = np.random.default_rng(5)
     pos = jnp.asarray(
         np.stack([rng.integers(0, s, 257) for s in spec.shape], axis=1), jnp.int32
     )
-    want = nttd.apply_at_positions(params, pos, spec, cfg_ref)
-    got = nttd.apply_at_positions(params, pos, spec, cfg_fused)
+    want = nttd.apply_at_positions(params, pos, spec, cfg)
+    got = ops.nttd_decode_tile(spec.fold_indices(pos),
+                               *nttd.fused_decode_inputs(params, spec, cfg))
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
     )

@@ -291,10 +291,9 @@ def _nttd_payload(tmp_path) -> tuple[str, tuple[int, ...]]:
 
 def test_socket_fleet_decode_is_one_stitched_trace(tmp_path, recorder,
                                                    monkeypatch):
-    # fused impl routes worker decode through ops.nttd_decode_tile, so
-    # the trace must contain kernel_decode spans; spawned workers inherit
-    # the env (REPRO_TRACE included) from this process
-    monkeypatch.setenv("REPRO_DECODE_IMPL", "fused")
+    # a served decode runs ops.nttd_decode_tile, so the trace must
+    # contain kernel_decode spans; spawned workers inherit the env
+    # (REPRO_TRACE) from this process
     monkeypatch.setenv("REPRO_TRACE", "1")
     path, shape = _nttd_payload(tmp_path)
     fleet = FleetFrontend(

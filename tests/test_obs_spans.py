@@ -34,9 +34,8 @@ FIT_SPANS = {"fit.sample": "fit.update", "fit.dispatch": "fit.update",
 
 
 @pytest.fixture()
-def service(tmp_path, monkeypatch):
-    """A CodecService serving a small NTTD payload on the fused path."""
-    monkeypatch.setenv("REPRO_DECODE_IMPL", "fused")
+def service(tmp_path):
+    """A CodecService serving a small NTTD payload."""
     spec = make_folding_spec(SHAPE, None)
     cfg = nttd.NTTDConfig(rank=3, hidden=6)
     params = jax.tree.map(np.asarray, nttd.init_params(jax.random.PRNGKey(7), spec, cfg))

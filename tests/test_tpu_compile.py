@@ -2,9 +2,10 @@
 
 Interpret mode runs a Pallas kernel's body as plain XLA, so it cannot
 see what Mosaic refuses (unsupported reshapes, output layouts that do
-not match XLA's).  These cases hand the real TPU compiler the kernels
-at the paper's widths and the NTTD train epoch at ``MEDIUM``, and check
-that each kernel lowers to a ``tpu_custom_call``.  Nothing runs.
+not match XLA's).  These cases hand the real TPU compiler the decode
+tile and the NTTD train epoch at the configurations' widths, and check
+that the tile lowers to a ``tpu_custom_call`` and the train epoch to
+none.  Nothing runs.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and every
@@ -12,11 +13,13 @@ test worker imports this file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -75,19 +78,56 @@ def _has_decode_tile(compiled) -> bool:
 
 @pytest.mark.parametrize("batch", [1, 100, 256, 300, 4096])
 @pytest.mark.parametrize("rank,hidden", [(6, 12), (10, 18), (8, 16)])
-def test_decode_tile_compiles(one_chip, rank, hidden, batch):
+def test_decode_tile_compiles(one_chip, monkeypatch, rank, hidden, batch):
     """The fused decode tile at the paper's SMALL and MEDIUM widths, over
     the pems_sf folding, and at granite's over its d' 17 leaf, through
-    the wrapper's batch padding."""
+    the wrapper's batch padding.  The wrapper asks
+    ``jax.default_backend()`` for its path, which sees this host's CPU, so
+    the test answers for the described chip."""
     from repro.kernels import ops
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fold = GRANITE_EMBED if (rank, hidden) == (8, 16) else ((963, 144, 440), None)
     spec, operands = _fused_operands(rank, hidden, *fold)
     idx = jax.ShapeDtypeStruct((batch, spec.d_prime), jnp.int32, sharding=one_chip)
-    compiled = _compile(
-        lambda i, *w: ops.nttd_decode_tile(i, *w, impl="pallas"),
-        idx, *_shapes(operands, one_chip),
-    )
+    compiled = _compile(ops.nttd_decode_tile, idx, *_shapes(operands, one_chip))
+    assert _has_decode_tile(compiled)
+
+
+#: each preset over the shape of the benchmark configuration that uses it
+PRESET_SHAPES = {"SMALL": (265, 265, 28, 35), "MEDIUM": (963, 144, 440)}
+
+
+@pytest.mark.parametrize("batch", [1, 300, 4096])
+@pytest.mark.parametrize("preset", list(PRESET_SHAPES))
+def test_served_decode_compiles_to_the_tile(one_chip, monkeypatch, preset, batch):
+    """A payload's served decode (``CompressedTensor.decode``), with the
+    backend answering as the chip: the program it calls, with the
+    arguments it passes, compiles for the v5e and holds ``%decode_tile``."""
+    from repro.configs import tensorcodec_paper
+    from repro.core import nttd
+    from repro.core.codec import CompressedTensor
+    from repro.core.folding import make_folding_spec
+    from repro.kernels import decode_tile
+
+    widths, shape = getattr(tensorcodec_paper, preset), PRESET_SHAPES[preset]
+    spec = make_folding_spec(shape)
+    cfg = nttd.NTTDConfig(rank=widths.rank, hidden=widths.hidden)
+    params = nttd.init_params(jax.random.PRNGKey(0), spec, cfg)
+    ct = CompressedTensor(params, [np.arange(n) for n in shape], spec, cfg)
+    program, calls = decode_tile.decode_tile, []
+
+    def record(idx, *operands, **static):
+        calls.append(((idx, *operands), static))
+        return jnp.zeros((idx.shape[0],), operands[0].dtype)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(decode_tile, "decode_tile", record)
+    rng = np.random.default_rng(batch)
+    ct.decode(np.stack([rng.integers(0, n, batch) for n in shape], axis=1))
+    [(args, static)] = calls
+    assert static["interpret"] is False
+    compiled = program.lower(*_shapes(args, one_chip), **static).compile()
     assert _has_decode_tile(compiled)
 
 
@@ -112,55 +152,38 @@ def test_restore_slab_compiles(one_chip, monkeypatch):
     assert _has_decode_tile(compiled)
 
 
-@pytest.mark.parametrize("batch", [1, 300, 4096])
-def test_tt_contract_compiles(one_chip, batch):
-    from repro.kernels import ops
-
-    r, k = 10, 8
-    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
-    compiled = _compile(
-        lambda f, m, last: ops.tt_contract(f, m, last, impl="pallas"),
-        sds(batch, r), sds(batch, k, r, r), sds(batch, r),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+#: (shape, d') of the fit program at each width: SMALL over nyc, MEDIUM
+#: over pems_sf, granite's over its d' 17 leaf
+FIT_CASES = {"SMALL": ((265, 265, 28, 35), None), "MEDIUM": ((963, 144, 440), None),
+             "granite": GRANITE_EMBED}
 
 
-@pytest.mark.parametrize("batch", [1, 300, 4096])
-def test_lstm_scan_compiles(one_chip, batch):
-    from repro.kernels import ops
-
-    t, h = 10, 18
-    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
-    compiled = _compile(
-        lambda x, wi, wh, b: ops.lstm_scan(x, wi, wh, b, impl="pallas"),
-        sds(batch, t, h), sds(h, 4 * h), sds(h, 4 * h), sds(4 * h),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_train_epoch_compiles(one_chip):
-    """The fit program at MEDIUM over the full pems_sf shape: plain XLA
-    (training keeps the ref impl, the tile has no VJP), and it must fit
-    the chip's 16 GB."""
-    from repro.configs.tensorcodec_paper import MEDIUM
+@pytest.mark.parametrize("case", list(FIT_CASES))
+def test_train_epoch_compiles(one_chip, case):
+    """The fit program over a configuration's shape: plain XLA, no Pallas
+    call (training runs the jnp chain, the tile has no VJP), and it must
+    fit the chip's 16 GB."""
+    from repro.configs.tensorcodec_paper import MEDIUM, SMALL
     from repro.core import codec, nttd
     from repro.core.folding import make_folding_spec
     from repro.optim import optimizers
 
-    shape, steps = (963, 144, 440), 64
-    spec = make_folding_spec(shape)
-    cfg = nttd.NTTDConfig(rank=MEDIUM.rank, hidden=MEDIUM.hidden, kernel_impl="ref")
-    opt = optimizers.adam(MEDIUM.lr)
+    widths = {"SMALL": SMALL, "MEDIUM": MEDIUM,
+              "granite": dataclasses.replace(MEDIUM, rank=8, hidden=16)}[case]
+    (shape, d_prime), steps, bsz = FIT_CASES[case], 64, widths.batch_size
+    spec = make_folding_spec(shape, d_prime)
+    cfg = nttd.NTTDConfig(rank=widths.rank, hidden=widths.hidden)
+    opt = optimizers.adam(widths.lr)
     params = jax.eval_shape(lambda k: nttd.init_params(k, spec, cfg), jax.random.PRNGKey(0))
     opt_state = jax.eval_shape(opt.init, params)
     epoch = codec._make_train_epoch(spec, cfg, opt)
-    bsz = MEDIUM.batch_size
     compiled = epoch.lower(
         _shapes(params, one_chip),
         _shapes(opt_state, one_chip),
         jax.ShapeDtypeStruct((steps, bsz, len(shape)), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((steps, bsz), jnp.float32, sharding=one_chip),
     ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes
     assert 0 < used < 16 * 2**30
