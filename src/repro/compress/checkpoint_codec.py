@@ -287,8 +287,11 @@ class RestorePlan:
     codecs have no device decode; ``run`` decodes them on the host.
 
     Spans: ``ckpt.restore`` (``leaves``, ``entries``) around ``run``, and
-    ``ckpt.restore_slab`` (``leaf``, ``entries``, ``d_prime``) around each
-    slab's dispatch.  ``metrics`` counts ``ckpt.restored_entries``.
+    ``ckpt.restore_slab`` (``leaf``, ``entries``, ``d_prime``, and the
+    split of the leaf's folded indices: ``tail``, the entries of its tail
+    table, and ``rows``, the head rows a slab builds; ``nttd.slab_split``)
+    around each slab's dispatch.  ``metrics`` counts
+    ``ckpt.restored_entries``.
     """
 
     def __init__(self, payload: dict, slab: int = nttd.SLAB_ENTRIES):
@@ -329,7 +332,7 @@ class RestorePlan:
         key, k = self.steps[i % len(self.steps)]
         s = self.slabs[key]
         with obs.span("ckpt.restore_slab", leaf=key, entries=s.entries(k),
-                      d_prime=s.d_prime):
+                      d_prime=s.d_prime, tail=s.tail, rows=s.rows):
             self.buffers[key] = s.write(self.buffers[key], k)
         self._restored.inc(s.entries(k))
         return key, k

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -173,36 +174,92 @@ from repro.codecs.indexing import flat_to_multi  # noqa: E402, F401
 SLAB_ENTRIES = 1 << 22
 
 
-def _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab):
+#: most entries of a dense decode's tail table (``slab_split``)
+TAIL_ENTRIES = 1 << 16
+
+
+def slab_split(shape: tuple[int, ...], slab: int) -> tuple[int, int, int]:
+    """``(t, w, rows)`` of a dense decode in slabs.  The tail is the modes
+    from ``t`` on: the longest run of trailing modes whose product ``w``
+    stays within ``TAIL_ENTRIES``, or the last mode alone when it is
+    longer.  ``rows`` is the rows of ``w`` entries whose folded indices a
+    slab builds to cover any ``slab`` consecutive entries, never more
+    than the tensor has."""
+    t, w = len(shape) - 1, shape[-1]
+    while t and w * shape[t - 1] <= TAIL_ENTRIES:
+        t -= 1
+        w *= shape[t]
+    return t, w, min(-(-(slab + w - 1) // w), math.prod(shape) // w)
+
+
+def tail_table(inv_pi, spec: FoldingSpec) -> np.ndarray:
+    """``[d', w]`` int32: the folded indices of the tail's ``w`` entries
+    in original row-major order, each mode mapped to its position through
+    ``inv_pi``, with the head modes at 0.  The fold's digits of different
+    modes never carry into each other, so an entry's folded index is its
+    head row's part plus its tail entry's part."""
+    shape = tuple(spec.shape)
+    t, w, _ = slab_split(shape, 1)
+    out = np.zeros((spec.d_prime, w), np.int64)
+    rem = np.arange(w)
+    for k in reversed(range(t, len(shape))):
+        pos = np.asarray(inv_pi[k])[rem % shape[k]][None, :]
+        rem //= shape[k]
+        digits = (pos // spec.strides[k][:, None]) % spec.factors[k][:, None]
+        out += digits * spec.fstrides[k][:, None]
+    return out.astype(np.int32)
+
+
+def slab_indices(start, head_inv_pi, tail, *, shape, factors, slab):
+    """Folded indices ``[d', slab]`` of entries ``[start, start + slab)``
+    of the original row-major order: the head part of each of ``rows``
+    rows of ``w`` entries (its head modes unravelled, mapped through
+    ``head_inv_pi`` on the device, and folded) broadcast-added to the
+    tail table (``tail_table``), and the slab sliced out of the block.
+    The block starts at ``start``'s row, shifted back so that it ends
+    within the tensor; no index is gathered per entry."""
+    spec = spec_from_factors(shape, factors)
+    t, w, rows = slab_split(shape, slab)
+    r0 = jnp.minimum(start // w, math.prod(shape[:t]) - rows)
+    if t:
+        rem = r0 + jnp.arange(rows, dtype=jnp.int32)
+        pos = [None] * t
+        for k in reversed(range(t)):
+            pos[k] = jnp.take(head_inv_pi[k], rem % shape[k])
+            rem = rem // shape[k]
+        head = spec.fold_modes(pos + [0] * (len(shape) - t)).T  # [d', rows]
+    else:
+        head = jnp.zeros((spec.d_prime, rows), jnp.int32)
+    block = (head[:, :, None] + tail[:, None, :]).reshape(spec.d_prime, rows * w)
+    return jax.lax.dynamic_slice(block, (0, start - r0 * w), (spec.d_prime, slab))
+
+
+def _slab_values(start, operands, head_inv_pi, tail, mean, std, shape, factors, slab):
     """Entries ``[start, start + slab)`` of the original row-major order:
-    flat indices from an iota, unravelled, each mode mapped to its
-    position through ``inv_pi`` (gathered on the device), folded, decoded
-    through the fused tile and de-normalised.  [slab] float32."""
-    rem = start + jnp.arange(slab, dtype=jnp.int32)
-    pos = [None] * len(shape)
-    for k in reversed(range(len(shape))):
-        pos[k] = jnp.take(inv_pi[k], rem % shape[k])
-        rem = rem // shape[k]
-    folded = spec_from_factors(shape, factors).fold_modes(pos)
-    vals = ops.nttd_decode_tile(folded, *operands)
+    folded indices (``slab_indices``), decoded through the fused tile and
+    de-normalised.  [slab] float32."""
+    folded = slab_indices(start, head_inv_pi, tail, shape=shape, factors=factors, slab=slab)
+    vals = ops.nttd_decode_tile(folded.T, *operands)
     return vals.astype(jnp.float32) * std + mean
 
 
 @functools.partial(
     jax.jit, static_argnames=("shape", "factors", "slab"), donate_argnums=(0,)
 )
-def restore_slab(buf, start, operands, inv_pi, mean, std, *, shape, factors, slab):
+def restore_slab(buf, start, operands, head_inv_pi, tail, mean, std, *, shape, factors,
+                 slab):
     """``buf`` (flat float32, donated) with one slab of the dense decode
     written at ``start``."""
-    vals = _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab)
+    vals = _slab_values(start, operands, head_inv_pi, tail, mean, std, shape, factors, slab)
     return jax.lax.dynamic_update_slice(buf, vals.astype(buf.dtype), (start,))
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "factors", "slab"))
-def slab_sq_err(x, first, start, operands, inv_pi, mean, std, *, shape, factors, slab):
+def slab_sq_err(x, first, start, operands, head_inv_pi, tail, mean, std, *, shape, factors,
+                slab):
     """Summed squared error of one slab of the dense decode against ``x``
     (the same slab of the original tensor), over entries from ``first``."""
-    vals = _slab_values(start, operands, inv_pi, mean, std, shape, factors, slab)
+    vals = _slab_values(start, operands, head_inv_pi, tail, mean, std, shape, factors, slab)
     flat = start + jnp.arange(slab, dtype=jnp.int32)
     return jnp.sum(jnp.where(flat >= first, jnp.square(x - vals), 0.0))
 
@@ -210,9 +267,10 @@ def slab_sq_err(x, first, start, operands, inv_pi, mean, std, *, shape, factors,
 class DenseSlabs:
     """A tensor's dense decode on the device, in fixed slabs of its
     original row-major order.  No index or value crosses the host per
-    slab: the operands, the per-mode inverse orders and the normalisation
-    are uploaded once.  The decode is the fused tile's, whatever kernel
-    ``cfg`` names (on a TPU the tile is the decode path).
+    slab: the operands, the head modes' inverse orders, the tail table
+    (``tail_table``; ``tail`` entries, ``rows`` of them a slab, from
+    ``slab_split``) and the normalisation are uploaded once.  The decode
+    is the fused tile's (on a TPU the tile is the decode path).
 
     Slab ``k`` holds entries ``[k * slab, (k + 1) * slab)``; the last one
     is shifted back to end at the tensor's end, so it overlaps the slab
@@ -233,6 +291,7 @@ class DenseSlabs:
         self.n_slabs = -(-n // self.slab)
         if inv_pi is None:
             inv_pi = [np.arange(m) for m in self.shape]
+        t, self.tail, self.rows = slab_split(self.shape, self.slab)
         self._static = {
             "shape": self.shape,
             "factors": tuple(tuple(int(f) for f in row) for row in spec.factors),
@@ -240,7 +299,8 @@ class DenseSlabs:
         }
         self._args = jax.device_put((
             fused_decode_inputs(params, spec, cfg),
-            tuple(jnp.asarray(p, jnp.int32) for p in inv_pi),
+            tuple(jnp.asarray(p, jnp.int32) for p in inv_pi[:t]),
+            tail_table(inv_pi, spec),
             jnp.float32(mean),
             jnp.float32(std),
         ))
@@ -249,7 +309,8 @@ class DenseSlabs:
         return min(k * self.slab, self.n - self.slab)
 
     def entries(self, k: int) -> int:
-        """Entries that slab ``k`` adds (the tail's overlap not counted)."""
+        """Entries that slab ``k`` adds (the last slab's overlap not
+        counted)."""
         return min(self.slab, self.n - k * self.slab)
 
     def write(self, buf: jax.Array, k: int) -> jax.Array:

@@ -148,6 +148,13 @@ def test_the_restore_records_its_spans_and_counts_its_entries(checkpoint):
     assert {s.attrs["leaf"] for s in slabs} == set(plan.slabs)
     assert all(s.attrs["d_prime"] >= 2 for s in slabs)
     assert plan.metrics.counter("ckpt.restored_entries").value == plan.entries
+    for s in slabs:  # the split of each leaf's folded indices, by the rule
+        leaf = plan.slabs[s.attrs["leaf"]]
+        runs = [int(np.prod(leaf.shape[k:])) for k in range(len(leaf.shape))]
+        tail = max([p for p in runs if p <= 2**16], default=leaf.shape[-1])
+        assert s.attrs["tail"] == tail, s.attrs
+        # rows of the tail that cover any slab, no more than the leaf has
+        assert s.attrs["rows"] == min(-(-(leaf.slab + tail - 1) // tail), leaf.n // tail)
 
 
 def _old_to_dense(ct):

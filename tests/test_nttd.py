@@ -1,10 +1,17 @@
 """NTTD model unit tests (paper Alg. 2)."""
+import functools
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import nttd
-from repro.core.folding import make_folding_spec
+from repro.core.folding import make_folding_spec, spec_from_factors
+
+GRANITE = Path(__file__).resolve().parents[1] / "bench" / "configs" / "granite4h_small-ep9.json"
 
 
 def _setup(shape=(12, 10, 8), rank=4, hidden=8):
@@ -88,3 +95,66 @@ def test_generate_tensor_matches_pointwise():
     np.testing.assert_allclose(
         full[tuple(pos[:, j] for j in range(3))], np.asarray(vals), rtol=1e-5, atol=1e-5
     )
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "factors", "slab"))
+def _per_entry_indices(start, inv_pi, *, shape, factors, slab):
+    """The dense decode's folded indices as one entry at a time gives
+    them: each flat index unravelled, every mode mapped through its
+    ``inv_pi`` and folded.  [slab, d']"""
+    rem = start + jnp.arange(slab, dtype=jnp.int32)
+    pos = [None] * len(shape)
+    for k in reversed(range(len(shape))):
+        pos[k] = jnp.take(inv_pi[k], rem % shape[k])
+        rem = rem // shape[k]
+    return spec_from_factors(shape, factors).fold_modes(pos)
+
+
+def _slab_cases():
+    """(shape, d', slab, start, order) of every granite leaf at the
+    restore's slab, at its first, an interior start not aligned to its
+    tail, and its last (shifted) slab; and small shapes at each edge of
+    the split."""
+    leaves = [(tuple(x["shape"]), x["d_prime"], nttd.SLAB_ENTRIES, x["key"])
+              for x in json.loads(GRANITE.read_text())["leaves"]]
+    small = [((40, 30), None, 1000, "smaller-than-the-tail"),
+             ((6, 5, 4), None, 7, "one-table"),
+             ((5, 300, 300), None, 1000, "tail-not-dividing-the-slab"),
+             ((1, 1, 300, 700), None, 5000, "leading-ones"),
+             ((3, 70000), None, 1000, "trailing-mode-over-the-cap"),
+             ((70000,), None, 1000, "one-mode"),
+             ((5, 300, 300), None, 1000, "identity")]
+    cases = []
+    for shape, d_prime, slab, name in leaves + small:
+        n = int(np.prod(shape))
+        slab = min(slab, n)
+        starts = {"first": 0}
+        if n - slab > 2:  # odd, so not on a row of any of these (even) tails
+            starts["interior"] = (n - slab) // 2 | 1
+        if n > slab:
+            starts["last"] = n - slab
+        order = "identity" if name == "identity" else "random"
+        cases += [pytest.param(shape, d_prime, slab, start, order, id=f"{name}-{where}")
+                  for where, start in starts.items()]
+    return cases
+
+
+@pytest.mark.parametrize("shape,d_prime,slab,start,order", _slab_cases())
+def test_slab_indices_equal_the_per_entry_build(shape, d_prime, slab, start, order):
+    """The broadcast sum of head rows and the tail table gives every
+    entry's folded index exactly as the per-entry unravel, gather and
+    fold do, so the tile sees the same inputs."""
+    spec = make_folding_spec(shape, d_prime)
+    rng = np.random.default_rng(start + len(shape))
+    inv_pi = [np.arange(m) if order == "identity" else rng.permutation(m) for m in shape]
+    t = nttd.slab_split(shape, slab)[0]
+    static = {"shape": shape, "factors": tuple(tuple(int(f) for f in row) for row in spec.factors),
+              "slab": slab}
+    want = _per_entry_indices(jnp.int32(start), tuple(jnp.asarray(p, jnp.int32) for p in inv_pi),
+                              **static)
+    got = jax.jit(nttd.slab_indices, static_argnames=tuple(static))(
+        jnp.int32(start), tuple(jnp.asarray(p, jnp.int32) for p in inv_pi[:t]),
+        jnp.asarray(nttd.tail_table(inv_pi, spec)), **static)
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype == np.int32
+    assert np.array_equal(got.T, want)

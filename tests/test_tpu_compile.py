@@ -131,25 +131,47 @@ def test_served_decode_compiles_to_the_tile(one_chip, monkeypatch, preset, batch
     assert _has_decode_tile(compiled)
 
 
-def test_restore_slab_compiles(one_chip, monkeypatch):
-    """One slab program of the device restore at ``SLAB_ENTRIES``, over
-    granite's d' 17 leaf: the tile inside it keeps its name.  The slab
-    asks ``jax.default_backend()`` for its decode path, which sees this
-    host's CPU, so the test answers for the described chip."""
+#: granite leaves of the restore's slab programs: the widest d', the
+#: narrowest tail (72 entries) and the longest (d' 15, 16,768 entries)
+RESTORE_LEAVES = {"tok/embed": GRANITE_EMBED, "router": ((1, 10, 4096, 72), 12),
+                  "in_proj": ((1, 9, 4096, 16768), 15)}
+
+
+def _gathers_of(compiled, elements: int) -> list[str]:
+    """The optimised program's gather instructions whose result holds at
+    least ``elements`` values."""
+    found = []
+    for m in re.finditer(r"%\S+ = \w+\[([\d,]*)\]\S* gather\(", compiled.as_text()):
+        if int(np.prod([int(x) for x in m.group(1).split(",") if x])) >= elements:
+            found.append(m.group(0))
+    return found
+
+
+@pytest.mark.parametrize("leaf", list(RESTORE_LEAVES))
+def test_restore_slab_compiles(one_chip, monkeypatch, leaf):
+    """One slab program of the device restore at ``SLAB_ENTRIES`` over a
+    granite leaf: the tile inside it keeps its name, and the folded
+    indices come from the head rows and the tail table, with no gather
+    per entry.  The slab asks ``jax.default_backend()`` for its decode
+    path, which sees this host's CPU, so the test answers for the
+    described chip."""
     from repro.core import nttd
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    shape, d_prime = GRANITE_EMBED
+    shape, d_prime = RESTORE_LEAVES[leaf]
     spec, operands = _fused_operands(8, 16, shape, d_prime)
+    slab = min(nttd.SLAB_ENTRIES, spec.n_entries)
+    t, w, _ = nttd.slab_split(shape, slab)
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
     compiled = nttd.restore_slab.lower(
         sds((spec.n_entries,), jnp.float32), sds((), jnp.int32),
-        _shapes(operands, one_chip), tuple(sds((m,), jnp.int32) for m in shape),
-        sds((), jnp.float32), sds((), jnp.float32),
+        _shapes(operands, one_chip), tuple(sds((m,), jnp.int32) for m in shape[:t]),
+        sds((spec.d_prime, w), jnp.int32), sds((), jnp.float32), sds((), jnp.float32),
         shape=shape, factors=tuple(tuple(int(f) for f in row) for row in spec.factors),
-        slab=nttd.SLAB_ENTRIES,
+        slab=slab,
     ).compile()
     assert _has_decode_tile(compiled)
+    assert not _gathers_of(compiled, slab)
 
 
 #: (shape, d') of the fit program at each width: SMALL over nyc, MEDIUM
